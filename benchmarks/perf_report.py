@@ -5,7 +5,7 @@ import json
 import os
 from typing import Dict, Optional
 
-from .roofline import ARTIFACT_DIR, V5E_LINK, analytic_memory_s, row
+from .roofline import ARTIFACT_DIR, analytic_memory_s, row
 
 
 def load(arch: str, shape: str, mesh: str = "16x16", tag: str = ""

@@ -13,11 +13,11 @@ import json
 import os
 from typing import Dict, List, Optional
 
+from repro.core.hardware import chip_peaks
+
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "artifacts", "dryrun")
 
-V5E_FLOPS = 197e12
-V5E_HBM = 819e9
-V5E_LINK = 50e9
+V5E = chip_peaks("TPU v5 lite")
 
 
 def load_cells(mesh: str = "16x16", tag: str = "") -> List[Dict]:
@@ -53,7 +53,7 @@ def analytic_memory_s(cell: Dict) -> Optional[float]:
             + 4 * r.get("working_set", 0.0)
     else:
         traffic = p + r.get("kv_cache", 0.0) + r.get("working_set", 0.0)
-    return traffic / V5E_HBM
+    return traffic / V5E.hbm_bw
 
 
 def row(cell: Dict) -> Dict:
@@ -67,7 +67,7 @@ def row(cell: Dict) -> Dict:
     cp = cell.get("collectives", {}).get("by_kind", {}).get(
         "collective-permute")
     if cp and cp.get("wire_bytes_bf16", 0) == 0 and cp.get("bytes", 0) > 0:
-        coll = coll + 0.5 * cp["bytes"] / V5E_LINK
+        coll = coll + 0.5 * cp["bytes"] / V5E.ici_bw
     dom_terms = {"compute": comp, "memory(analytic)": mem_a or 0.0,
                  "collective": coll}
     dominant = max(dom_terms, key=dom_terms.get)

@@ -30,6 +30,9 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import figures, fleet_bench, kernel_bench, paper_tables, roofline
 
     def fleet() -> list:

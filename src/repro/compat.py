@@ -1,11 +1,8 @@
-"""JAX version compatibility shims.
+"""Mesh and shard_map helpers for the installed JAX (0.9).
 
-The repo targets the modern API surface (``jax.shard_map`` with
-``check_vma``/``axis_names``, ``jax.make_mesh(..., axis_types=...)``)
-but must also run on older toolchains (e.g. jax 0.4.x) where those live
-in ``jax.experimental.shard_map`` with ``check_rep``/``auto`` and
-``jax.sharding.AxisType`` does not exist.  Every mesh/shard_map call in
-``src/`` goes through these two helpers.
+Every mesh/shard_map call in ``src/`` goes through these two helpers, so
+the options this repo relies on are set in one place: Auto axis types on
+every mesh axis, and replication (vma) checks off in shard_map regions.
 """
 from __future__ import annotations
 
@@ -13,41 +10,21 @@ from typing import Optional, Sequence, Set
 
 import jax
 
-_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=(_AXIS_TYPE.Auto,) * len(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-
-
-def axis_size(axis: str) -> int:
-    """Static size of a named mesh axis from inside a shard_map region
-    (``jax.lax.axis_size`` where available, else the psum(1) idiom)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs,
               axis_names: Optional[Set[str]] = None):
-    """``jax.shard_map`` across versions, replication checks disabled.
+    """``jax.shard_map`` with replication checks disabled.
 
-    ``axis_names``: the manual axes of a partial-manual region (newer
-    jax keyword); on the legacy API it maps to ``auto`` = the mesh axes
-    NOT in ``axis_names``.  ``None`` means fully manual (all axes).
+    ``axis_names``: the manual axes of a partial-manual region; ``None``
+    means fully manual (all axes).
     """
-    if _NEW_SHARD_MAP:
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False, **kw)
-    from jax.experimental.shard_map import shard_map as _legacy
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False, **kw)
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kw)

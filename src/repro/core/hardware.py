@@ -56,6 +56,24 @@ DEVICES: Dict[str, DeviceSpec] = {
     "a100": A100, "orin": ORIN, "thor": THOR, "tpu-v5e": TPU_V5E,
 }
 
+# Published chip peaks keyed by ``jax.Device.device_kind`` — the one
+# table every on-chip measurement reads.  TPU v5e: 197 TFLOP/s bf16,
+# 819 GB/s over 16 GB HBM, 50 GB/s per ICI link (Google Cloud
+# documentation, "TPU v5e").
+CHIP_PEAKS: Dict[str, DeviceSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def chip_peaks(device_kind: str) -> DeviceSpec:
+    """Peaks of the chip JAX reports as ``device_kind``; an unknown kind
+    is an error, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no chip peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(CHIP_PEAKS)}") from None
+
 
 # ------------------------------------------------------------------ Eq. 2
 def layer_latency(c: LayerCost, dev: DeviceSpec, *, parallel: float = 1.0

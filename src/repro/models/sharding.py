@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 from typing import Any, Dict, Optional, Tuple
 
@@ -204,8 +205,32 @@ def spec_bytes(specs: Tree) -> int:
     return total
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "scale",
+                                             "stacked"))
+def _draw_leaf(key: jax.Array, shape, dtype, scale: float, stacked: bool):
+    """``normal * scale`` drawn straight into ``dtype`` on the default
+    device.  A ``stacked`` leaf (leading ``layers`` axis) is filled one
+    layer slice at a time inside a loop, so the float32 draw never exists
+    for more than one layer: the peak is the leaf plus one layer."""
+    def draw(k, shp):
+        return (jax.random.normal(k, shp, jnp.float32) * scale).astype(dtype)
+
+    if not stacked:
+        return draw(key, shape)
+
+    def fill(i, buf):
+        return jax.lax.dynamic_update_index_in_dim(
+            buf, draw(jax.random.fold_in(key, i), shape[1:]), i, 0)
+
+    return jax.lax.fori_loop(0, shape[0], fill, jnp.zeros(shape, dtype))
+
+
 def init_params(specs: Tree, key: jax.Array) -> Tree:
-    """Materialise a random parameter tree from a ParamSpec tree."""
+    """Materialise a random parameter tree from a ParamSpec tree.
+
+    Each leaf is drawn by its own jitted program in its target dtype, so
+    initialising a model whose bf16 parameters nearly fill the device
+    needs no float32 copy of any whole leaf."""
     leaves, treedef = jax.tree_util.tree_flatten(specs, is_leaf=is_spec)
     keys = jax.random.split(key, max(len(leaves), 1))
 
@@ -216,6 +241,7 @@ def init_params(specs: Tree, key: jax.Array) -> Tree:
             return jnp.ones(s.shape, s.dtype)
         fan_in = s.shape[-2] if len(s.shape) >= 2 else max(s.shape[-1], 1)
         scale = s.scale if s.scale is not None else fan_in ** -0.5
-        return (jax.random.normal(k, s.shape, jnp.float32) * scale).astype(s.dtype)
+        return _draw_leaf(k, s.shape, jnp.dtype(s.dtype), float(scale),
+                          stacked=s.axes[:1] == ("layers",))
 
     return jax.tree_util.tree_unflatten(treedef, [one(s, k) for s, k in zip(leaves, keys)])

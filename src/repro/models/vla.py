@@ -219,16 +219,26 @@ def vla_backbone(cfg, params, patches, tokens, *, remat=False):
     return rmsnorm(x, params["final_norm"], cfg.norm_eps)
 
 
+def detok_logits(cfg, params, h: jax.Array) -> jax.Array:
+    """Detok head: logits (B, action_dim, vocab) at the last
+    ``action_dim`` positions of the final-normed hidden states ``h``."""
+    return unembed(params["head"], h[:, -cfg.action_dim:], cfg.vocab_size)
+
+
+def detok_action(logits: jax.Array) -> jax.Array:
+    """Greedy action tokens -> action (B, 1, action_dim): 256 uniform
+    bins over [-1, 1] at the vocab tail."""
+    toks = jnp.argmax(logits, -1)                         # (B, action_dim)
+    act = (toks.astype(jnp.float32) % 256) / 127.5 - 1.0
+    return act[:, None, :]
+
+
 def vla_forward(cfg, params, patches, tokens, key=None):
     """Inference: returns action (B, horizon, action_dim)."""
     h = vla_backbone(cfg, params, patches, tokens)
     kind = cfg.vla_action_head
     if kind in ("detok", ""):
-        logits = unembed(params["head"], h[:, -cfg.action_dim:], cfg.vocab_size)
-        toks = jnp.argmax(logits, -1)                     # (B, action_dim)
-        # de-tokenize: 256 uniform bins over [-1, 1] at the vocab tail
-        act = (toks.astype(jnp.float32) % 256) / 127.5 - 1.0
-        return act[:, None, :]
+        return detok_action(detok_logits(cfg, params, h))
     cog = h[:, -1]                                        # cognition feature
     if kind == "mlp":
         p = params["action"]
@@ -278,7 +288,7 @@ def vla_loss(cfg, params, patches, tokens, action_labels, key) -> jax.Array:
     h = vla_backbone(cfg, params, patches, tokens, remat=cfg.remat)
     kind = cfg.vla_action_head
     if kind in ("detok", ""):
-        logits = unembed(params["head"], h[:, -cfg.action_dim:], cfg.vocab_size)
+        logits = detok_logits(cfg, params, h)
         bins = jnp.clip(((action_labels[:, 0] + 1) * 127.5), 0, 255).astype(
             jnp.int32)
         return softmax_xent(logits, bins)

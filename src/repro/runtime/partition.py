@@ -4,15 +4,16 @@ The model's layer stack is cut at a *dynamic* split index that lives inside a
 static **parameter-sharing pool** ``[pool_start, pool_end)`` (paper §IV-B-2):
 both tiers hold the pool layers' weights, so moving the split inside the pool
 needs **no weight shipping and no recompilation** — the split index is a
-traced argument, and each pool layer runs under a ``lax.cond`` keyed on
-``layer_idx < split``.
+traced argument, clamped into the pool on the host, and each tier runs its
+blocks in one loop whose bound is the split (the edge ``[0, split)``, the
+cloud ``[split, L)``).
 
 Multi-cut placements (``core/placement.py``) add a **second pool**
 ``[pool2_start, pool2_end)`` around the cloud→edge tail cut of an
-edge→cloud→edge plan: the cloud runs pool-2 layers with ``layer_idx <
-split2`` and the edge tail (including the final norm / LM head / action
-decode) runs the rest — both cuts are traced arguments, so moving either
-one inside its pool recompiles nothing.  A two-pool run ships two
+edge→cloud→edge plan: the cloud runs blocks ``[split, split2)`` and the
+edge tail (including the final norm / LM head / action decode) runs the
+rest — both cuts are traced arguments, so moving either one inside its
+pool recompiles nothing.  A two-pool run ships two
 payloads: the uplink cut activation (``codec``) and the downlink tail
 activation (``codec2``).
 
@@ -54,10 +55,9 @@ data-dependent shape logic), outside every jitted forward.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 import warnings
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -68,8 +68,8 @@ from ..core.telemetry import Span
 from ..kernels.activation_codec import ops as codec
 from ..models import transformer as T
 from ..models import vla as V
-from ..models.layers import embed, rmsnorm, unembed
-from ..models.transformer import block_forward, block_decode, _layer_slice
+from ..models.layers import embed, rmsnorm
+from ..models.transformer import block_forward
 
 Tree = Any
 
@@ -139,31 +139,29 @@ class SplitPlan:
 
 
 # ------------------------------------------------------------------ helpers
-def _masked_stack(cfg, pool_params: Tree, x: jax.Array, positions, split,
-                  offset: int, side: str, *, is_moe: bool):
-    """Run pool layers under lax.cond(active-on-this-side).
+def _run_blocks(cfg, blocks: Tree, x: jax.Array, positions, lo, hi, *,
+                is_moe: bool):
+    """Run blocks ``[lo, hi)`` of the stacked ``blocks`` tree.
 
-    ``side`` names the *predicate*, not the physical tier: ``"edge"`` runs
-    layers with ``i < split`` (the below-the-cut half), ``"cloud"`` those
-    with ``i >= split``.  A two-pool plan reuses the same predicates around
-    its second cut with the tiers swapped — the cloud owns the below-half
-    of pool 2 and the edge tail the above-half."""
-    n = jax.tree_util.tree_leaves(pool_params)[0].shape[0]
+    One loop step per block, each indexing its layer's weights out of the
+    stack, so the program never copies more than one layer.  Either bound
+    may be traced — a dynamic cut is a loop bound, so moving it inside its
+    pool recompiles nothing and no block runs on both tiers."""
+    def body(i, h):
+        pl = jax.tree_util.tree_map(
+            lambda w: jax.lax.dynamic_index_in_dim(w, i, keepdims=False),
+            blocks)
+        out, _, _ = block_forward(cfg, pl, h, positions, is_moe=is_moe)
+        return out
 
-    def body(h, xs):
-        pl, i = xs
-        on = (i < split) if side == "edge" else (i >= split)
+    return jax.lax.fori_loop(lo, hi, body, x)
 
-        def run(a):
-            out, _, _ = block_forward(cfg, pl, a, positions, is_moe=is_moe)
-            return out
 
-        h = jax.lax.cond(on, run, lambda a: a, h)
-        return h, None
-
-    idx = jnp.arange(offset, offset + n)
-    x, _ = jax.lax.scan(body, x, (pool_params, idx))
-    return x
+def _clip(v, lo: int, hi: int):
+    """Clamp a static or traced layer index into ``[lo, hi]``."""
+    if isinstance(v, int):
+        return max(lo, min(v, hi))
+    return jnp.clip(v, lo, hi)
 
 
 def _codec_block(D: int) -> int:
@@ -384,7 +382,9 @@ class LMSplitExecutor:
     """
 
     def __init__(self, cfg, plan: SplitPlan):
-        assert cfg.family in ("dense", "moe")
+        if cfg.family not in ("dense", "moe"):
+            raise ValueError(f"LMSplitExecutor runs dense/moe LMs, not "
+                             f"family {cfg.family!r}")
         assert 0 <= plan.pool_start <= plan.pool_end <= cfg.n_layers
         if plan.two_pool:
             assert plan.pool_end <= plan.pool2_start \
@@ -397,100 +397,47 @@ class LMSplitExecutor:
             self._cloud_mid = jax.jit(self._cloud_mid_fwd)
             self._tail = jax.jit(self._tail_fwd)
 
-    # -- groups bookkeeping (dense vs moe layer groups)
-    def _block_at(self, params, i: int) -> Tuple[Tree, bool]:
-        cfg = self.cfg
-        if cfg.family == "moe" and i >= cfg.first_dense_layers:
-            return _layer_slice(params["moe_blocks"],
-                                i - cfg.first_dense_layers), True
-        name = "dense_blocks" if cfg.family == "moe" else "blocks"
-        return _layer_slice(params[name], i), False
+    def _span(self, params, x, positions, lo, hi):
+        """Blocks ``[lo, hi)`` across the dense/MoE layer groups; either
+        bound may be a traced cut."""
+        off = 0
+        for name, n, is_moe in T._groups(self.cfg):
+            x = _run_blocks(self.cfg, params[name], x, positions,
+                            _clip(lo - off, 0, n), _clip(hi - off, 0, n),
+                            is_moe=is_moe)
+            off += n
+        return x
 
-    def _group_params(self, params, start: int, end: int
-                      ) -> Tuple[Tree, bool]:
-        """Stacked params of blocks [start, end) (one pool's weights)."""
-        cfg = self.cfg
-        if cfg.family == "moe":
-            nd = cfg.first_dense_layers
-            assert start >= nd or end <= nd, \
-                "pool must not straddle the dense/moe group boundary"
-            if start >= nd:
-                grp = jax.tree_util.tree_map(
-                    lambda w: w[start - nd:end - nd], params["moe_blocks"])
-                return grp, True
-            grp = jax.tree_util.tree_map(
-                lambda w: w[start:end], params["dense_blocks"])
-            return grp, False
-        grp = jax.tree_util.tree_map(
-            lambda w: w[start:end], params["blocks"])
-        return grp, False
-
-    def _pool_params(self, params) -> Tuple[Tree, bool]:
-        return self._group_params(params, self.plan.pool_start,
-                                  self.plan.pool_end)
-
-    # -- edge side: embed + [0, pool_start) + masked pool
+    # -- edge side: embed + blocks [0, split)
     def _edge_fwd(self, params, tokens, split):
         cfg, plan = self.cfg, self.plan
-        S = tokens.shape[1]
-        positions = jnp.arange(S)
+        positions = jnp.arange(tokens.shape[1])
         x = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
-        for i in range(plan.pool_start):
-            pl, is_moe = self._block_at(params, i)
-            x, _, _ = block_forward(cfg, pl, x, positions, is_moe=is_moe)
-        pool, is_moe = self._pool_params(params)
-        if plan.pool_end > plan.pool_start:
-            x = _masked_stack(cfg, pool, x, positions, split,
-                              plan.pool_start, "edge", is_moe=is_moe)
+        x = self._span(params, x, positions, 0, split)
         return encode_activation(x, plan.wire_codec)
 
-    # -- cloud side (single-pool): masked pool + [pool_end, L) + head
+    # -- cloud side (single-pool): blocks [split, L) + head
     def _cloud_fwd(self, params, payload, split):
-        cfg, plan = self.cfg, self.plan
+        cfg = self.cfg
         x = decode_activation(payload, cfg.dtype)
         positions = jnp.arange(x.shape[1])
-        pool, is_moe = self._pool_params(params)
-        if plan.pool_end > plan.pool_start:
-            x = _masked_stack(cfg, pool, x, positions, split,
-                              plan.pool_start, "cloud", is_moe=is_moe)
-        for i in range(plan.pool_end, cfg.n_layers):
-            pl, is_moe = self._block_at(params, i)
-            x, _, _ = block_forward(cfg, pl, x, positions, is_moe=is_moe)
+        x = self._span(params, x, positions, split, cfg.n_layers)
         return T.lm_logits(cfg, params, x)
 
-    # -- cloud side (two-pool): masked pool + mid blocks + masked pool 2
+    # -- cloud side (two-pool): blocks [split, split2)
     def _cloud_mid_fwd(self, params, payload, split, split2):
         cfg, plan = self.cfg, self.plan
         x = decode_activation(payload, cfg.dtype)
         positions = jnp.arange(x.shape[1])
-        pool, is_moe = self._pool_params(params)
-        if plan.pool_end > plan.pool_start:
-            x = _masked_stack(cfg, pool, x, positions, split,
-                              plan.pool_start, "cloud", is_moe=is_moe)
-        for i in range(plan.pool_end, plan.pool2_start):
-            pl, is_moe = self._block_at(params, i)
-            x, _, _ = block_forward(cfg, pl, x, positions, is_moe=is_moe)
-        pool2, is_moe2 = self._group_params(params, plan.pool2_start,
-                                            plan.pool2_end)
-        if plan.pool2_end > plan.pool2_start:
-            # cloud owns the BELOW-split2 half of pool 2 ("edge" predicate)
-            x = _masked_stack(cfg, pool2, x, positions, split2,
-                              plan.pool2_start, "edge", is_moe=is_moe2)
+        x = self._span(params, x, positions, split, split2)
         return encode_activation(x, plan.codec2)
 
-    # -- edge tail (two-pool): masked pool 2 + [pool2_end, L) + head
+    # -- edge tail (two-pool): blocks [split2, L) + head
     def _tail_fwd(self, params, payload, split2):
-        cfg, plan = self.cfg, self.plan
+        cfg = self.cfg
         x = decode_activation(payload, cfg.dtype)
         positions = jnp.arange(x.shape[1])
-        pool2, is_moe2 = self._group_params(params, plan.pool2_start,
-                                            plan.pool2_end)
-        if plan.pool2_end > plan.pool2_start:
-            x = _masked_stack(cfg, pool2, x, positions, split2,
-                              plan.pool2_start, "cloud", is_moe=is_moe2)
-        for i in range(plan.pool2_end, cfg.n_layers):
-            pl, is_moe = self._block_at(params, i)
-            x, _, _ = block_forward(cfg, pl, x, positions, is_moe=is_moe)
+        x = self._span(params, x, positions, split2, cfg.n_layers)
         return T.lm_logits(cfg, params, x)
 
     # -- public API
@@ -549,8 +496,10 @@ class LMSplitExecutor:
 class VLASplitExecutor:
     """ViT + LLM (+ action head) split; pool(s) inside the LLM block range.
 
-    Layer indexing (matches core/structure.py): ViT blocks [0, Lv) —
-    always edge-side candidates; LLM blocks [Lv, Lv+L); action head after.
+    Layer indexing: ViT blocks [0, Lv) — always edge-side candidates; LLM
+    blocks [Lv, Lv+L); action head after.  ``core/structure.py``'s graph
+    has one more node, the ViT projection, between the two stacks;
+    ``launch/serve.executor_index`` maps a graph split onto this indexing.
     The dynamic pools must lie inside the LLM range; the ViT boundary is a
     static placement choice evaluated by the cost model (DESIGN.md §7).
 
@@ -562,7 +511,9 @@ class VLASplitExecutor:
     """
 
     def __init__(self, cfg, plan: SplitPlan, action_on_cloud: bool = True):
-        assert cfg.family == "vla"
+        if cfg.family != "vla":
+            raise ValueError(f"VLASplitExecutor runs VLA models, not "
+                             f"family {cfg.family!r}")
         self.cfg = cfg
         self.plan = plan
         Lv = cfg.vit_layers
@@ -577,11 +528,12 @@ class VLASplitExecutor:
             self._cloud_mid = jax.jit(self._cloud_mid_fwd)
             self._tail = jax.jit(self._tail_fwd)
 
-    def _blocks(self, params, start: int, end: int) -> Tree:
-        """Stacked LLM-block params [start, end) in graph indexing."""
+    def _span(self, params, x, positions, lo, hi):
+        """LLM blocks ``[lo, hi)`` in graph indexing; either bound may be
+        a traced cut."""
         Lv = self.cfg.vit_layers
-        return jax.tree_util.tree_map(
-            lambda w: w[start - Lv:end - Lv], params["blocks"])
+        return _run_blocks(self.cfg, params["blocks"], x, positions,
+                           lo - Lv, hi - Lv, is_moe=False)
 
     def _tail_slice(self) -> int:
         """Static downlink sequence length.  When pool 2 is degenerate at
@@ -601,94 +553,65 @@ class VLASplitExecutor:
 
     def _action_decode(self, params, x, key):
         """Final norm + action decode (models.vla.vla_forward tail) — runs
-        on whichever tier owns the last segment."""
+        on whichever tier owns the last segment.  Returns ``(action,
+        logits)``: the detok head's logits at the ``action_dim`` action
+        positions, ``None`` for heads that decode no tokens."""
         cfg = self.cfg
         h = rmsnorm(x, params["final_norm"], cfg.norm_eps)
         if cfg.vla_action_head in ("detok", ""):
-            logits = unembed(params["head"], h[:, -cfg.action_dim:])
-            toks = jnp.argmax(logits, -1)
-            act = (toks.astype(jnp.float32) % 256) / 127.5 - 1.0
-            return act[:, None, :]
-        cog = h[:, -1]
+            logits = V.detok_logits(cfg, params, h)
+            return V.detok_action(logits), logits
         if cfg.vla_action_head == "dit":
-            return V.dit_sample(cfg, params["action"], cog, key)
+            return V.dit_sample(cfg, params["action"], h[:, -1], key), None
         raise NotImplementedError(cfg.vla_action_head)
 
     def _edge_fwd(self, params, patches, tokens, split):
-        cfg, plan = self.cfg, self.plan
-        Lv = cfg.vit_layers
+        cfg = self.cfg
         img = V.vit_encode(cfg, params["vit"], patches)
         txt = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
         x = jnp.concatenate([img, txt], axis=1)
         positions = jnp.arange(x.shape[1])
-        for i in range(plan.pool_start - Lv):
-            pl = _layer_slice(params["blocks"], i)
-            x, _, _ = block_forward(cfg, pl, x, positions, is_moe=False)
-        pool = self._blocks(params, plan.pool_start, plan.pool_end)
-        if plan.pool_end > plan.pool_start:
-            x = _masked_stack(cfg, pool, x, positions, split,
-                              plan.pool_start, "edge", is_moe=False)
-        return encode_activation(x, plan.wire_codec)
+        x = self._span(params, x, positions, cfg.vit_layers, split)
+        return encode_activation(x, self.plan.wire_codec)
 
     def _cloud_fwd(self, params, payload, split, key):
-        cfg, plan = self.cfg, self.plan
-        Lv = cfg.vit_layers
+        cfg = self.cfg
         x = decode_activation(payload, cfg.dtype)
         positions = jnp.arange(x.shape[1])
-        pool = self._blocks(params, plan.pool_start, plan.pool_end)
-        if plan.pool_end > plan.pool_start:
-            x = _masked_stack(cfg, pool, x, positions, split,
-                              plan.pool_start, "cloud", is_moe=False)
-        for i in range(plan.pool_end - Lv, cfg.n_layers):
-            pl = _layer_slice(params["blocks"], i)
-            x, _, _ = block_forward(cfg, pl, x, positions, is_moe=False)
+        x = self._span(params, x, positions, split,
+                       cfg.vit_layers + cfg.n_layers)
         return self._action_decode(params, x, key)
 
-    # -- two-pool cloud trunk: masked pool + mid blocks + masked pool 2
+    # -- two-pool cloud trunk: LLM blocks [split, split2)
     def _cloud_mid_fwd(self, params, payload, split, split2):
         cfg, plan = self.cfg, self.plan
-        Lv = cfg.vit_layers
         x = decode_activation(payload, cfg.dtype)
         positions = jnp.arange(x.shape[1])
-        pool = self._blocks(params, plan.pool_start, plan.pool_end)
-        if plan.pool_end > plan.pool_start:
-            x = _masked_stack(cfg, pool, x, positions, split,
-                              plan.pool_start, "cloud", is_moe=False)
-        for i in range(plan.pool_end - Lv, plan.pool2_start - Lv):
-            pl = _layer_slice(params["blocks"], i)
-            x, _, _ = block_forward(cfg, pl, x, positions, is_moe=False)
-        pool2 = self._blocks(params, plan.pool2_start, plan.pool2_end)
-        if plan.pool2_end > plan.pool2_start:
-            # cloud owns the BELOW-split2 half of pool 2 ("edge" predicate)
-            x = _masked_stack(cfg, pool2, x, positions, split2,
-                              plan.pool2_start, "edge", is_moe=False)
+        x = self._span(params, x, positions, split, split2)
         k = self._tail_slice()
         if k:
             x = x[:, -k:]       # semantic downlink: only what the tail reads
         return encode_activation(x, plan.codec2)
 
-    # -- two-pool edge tail: masked pool 2 + remaining blocks + action
+    # -- two-pool edge tail: LLM blocks [split2, Lv+L) + action
     def _tail_fwd(self, params, payload, split2, key):
-        cfg, plan = self.cfg, self.plan
-        Lv = cfg.vit_layers
+        cfg = self.cfg
         x = decode_activation(payload, cfg.dtype)
         positions = jnp.arange(x.shape[1])
-        pool2 = self._blocks(params, plan.pool2_start, plan.pool2_end)
-        if plan.pool2_end > plan.pool2_start:
-            x = _masked_stack(cfg, pool2, x, positions, split2,
-                              plan.pool2_start, "cloud", is_moe=False)
-        for i in range(plan.pool2_end - Lv, cfg.n_layers):
-            pl = _layer_slice(params["blocks"], i)
-            x, _, _ = block_forward(cfg, pl, x, positions, is_moe=False)
+        x = self._span(params, x, positions, split2,
+                       cfg.vit_layers + cfg.n_layers)
         return self._action_decode(params, x, key)
 
     def run(self, params, patches, tokens, split: int,
             key: Optional[jax.Array] = None,
-            split2: Optional[int] = None, recorder=None):
+            split2: Optional[int] = None, recorder=None,
+            return_logits: bool = False):
         """One co-inference.  Single-pool plans return
         ``(action, uplink_payload)``; two-pool plans take the second cut
         ``split2`` and return ``(action, {"up": ..., "down": ...})`` with
-        the action decoded on the edge tail.  ``recorder`` as in
+        the action decoded on the edge tail.  ``return_logits`` inserts
+        the detok head's action-position logits (``None`` for other
+        heads): ``(action, logits, payload)``.  ``recorder`` as in
         ``LMSplitExecutor.run``."""
         split = jnp.int32(self.plan.clamp(split))
         t0 = time.perf_counter() if recorder is not None else 0.0
@@ -699,19 +622,19 @@ class VLASplitExecutor:
             t1 = time.perf_counter()
         key = key if key is not None else jax.random.PRNGKey(0)
         if not self.plan.two_pool:
-            action = self._cloud(params, payload, split, key)
-            if recorder is not None:
-                jax.block_until_ready(action)
-                _record_exec_spans(recorder, t0, t1, time.perf_counter())
-            return action, payload
-        split2 = jnp.int32(self.plan.clamp2(
-            split2 if split2 is not None else self.plan.pool2_end))
-        down = self._cloud_mid(params, payload, split, split2)
-        action = self._tail(params, down, split2, key)
+            action, logits = self._cloud(params, payload, split, key)
+        else:
+            split2 = jnp.int32(self.plan.clamp2(
+                split2 if split2 is not None else self.plan.pool2_end))
+            down = self._cloud_mid(params, payload, split, split2)
+            action, logits = self._tail(params, down, split2, key)
+            payload = {"up": payload, "down": down}
         if recorder is not None:
             jax.block_until_ready(action)
             _record_exec_spans(recorder, t0, t1, time.perf_counter())
-        return action, {"up": payload, "down": down}
+        if return_logits:
+            return action, logits, payload
+        return action, payload
 
     def run_streamed(self, params, patches, tokens, split: int,
                      n_chunks: int, key: Optional[jax.Array] = None,
@@ -726,9 +649,9 @@ class VLASplitExecutor:
         merged = merge_chunks(chunks)
         key = key if key is not None else jax.random.PRNGKey(0)
         if not self.plan.two_pool:
-            return self._cloud(params, merged, split_t, key), chunks
+            return self._cloud(params, merged, split_t, key)[0], chunks
         split2_t = jnp.int32(self.plan.clamp2(
             split2 if split2 is not None else self.plan.pool2_end))
         down = self._cloud_mid(params, merged, split_t, split2_t)
-        action = self._tail(params, down, split2_t, key)
+        action, _ = self._tail(params, down, split2_t, key)
         return action, {"up": chunks, "down": down}

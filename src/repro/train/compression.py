@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import axis_size
 
 Tree = Any
 
@@ -42,7 +41,7 @@ def ring_allreduce_int8(x: jax.Array, axis: str, rank=None) -> jax.Array:
     partial-manual shard_map regions (axis_index lowers to PartitionId,
     which GSPMD rejects there).
     """
-    N = axis_size(axis)
+    N = jax.lax.axis_size(axis)
     if N == 1:
         return x
     r = jax.lax.axis_index(axis) if rank is None else rank

@@ -150,3 +150,42 @@ def test_codec_error_bound_property(rows, blocks, scale):
 
 def test_codec_wire_bytes():
     assert codec_ref.wire_bytes((1, 17, 3072)) == 17 * 3072 + 17 * 24 * 4
+
+
+@pytest.mark.parametrize("rows", [1, 273, 600])
+def test_codec_kernels_any_row_count(rows):
+    """Row counts that no row tile divides (an OpenVLA cut is 273 rows)
+    are padded inside the kernels: int8 and int4, both directions, equal
+    the oracle bit for bit."""
+    x = jax.random.normal(jax.random.PRNGKey(rows), (1, rows, 512),
+                          jnp.bfloat16)
+    qi, si = codec_ops.quantize(x, impl="interpret")
+    qr, sr = codec_ref.quantize_int8(x)
+    assert qi.shape == qr.shape and si.shape == sr.shape
+    assert bool(jnp.all(qi == qr)) and bool(jnp.all(si == sr))
+    assert bool(jnp.all(codec_ops.dequantize(qr, sr, impl="interpret")
+                        == codec_ref.dequantize_int8(qr, sr)))
+    pi, s4i = codec_ops.quantize_int4(x, impl="interpret")
+    pr, s4r = codec_ref.quantize_int4(x)
+    assert bool(jnp.all(pi == pr)) and bool(jnp.all(s4i == s4r))
+    assert bool(jnp.all(codec_ops.dequantize_int4(pr, s4r, impl="interpret")
+                        == codec_ref.dequantize_int4(pr, s4r)))
+
+
+@pytest.mark.parametrize("impl", ["pallas", "interpret"])
+def test_codec_kernel_refuses_other_block(impl):
+    """A block size the kernels do not implement is an error, never a
+    silent switch to the jnp reference."""
+    x = jnp.ones((8, 512), jnp.float32)
+    q, s = codec_ref.quantize_int8(x, 64)
+    p4, s4 = codec_ref.quantize_int4(x, 64)
+    calls = [lambda: codec_ops.quantize(x, impl=impl, block=64),
+             lambda: codec_ops.dequantize(q, s, impl=impl, block=64),
+             lambda: codec_ops.quantize_int4(x, impl=impl, block=64),
+             lambda: codec_ops.dequantize_int4(p4, s4, impl=impl, block=64)]
+    for call in calls:
+        with pytest.raises(ValueError, match="block=64"):
+            call()
+    # the jnp path implements every block size
+    q2, s2 = codec_ops.quantize(x, impl="jnp", block=64)
+    assert bool(jnp.all(q2 == q))

@@ -78,3 +78,21 @@ def test_prefill_decode_shapes(arch):
                              jnp.int32(8))
     assert l2.shape[:2] == (2, 1)
     assert bool(jnp.all(jnp.isfinite(l2.astype(jnp.float32))))
+
+
+def test_init_params_draws_stacked_leaves_per_layer():
+    """Stacked leaves are filled one layer at a time in their target
+    dtype: every layer gets its own draw at the fan-in scale of the
+    whole leaf."""
+    from repro.models.sharding import init_params, spec
+    specs = {"w": spec((3, 64, 32), ("layers", "d_model", "ff")),
+             "n": spec((3, 64), ("layers", "d_model"), init="ones"),
+             "e": spec((100, 64), ("vocab", "d_model"), scale=1.0)}
+    p = init_params(specs, jax.random.PRNGKey(0))
+    w = p["w"].astype(jnp.float32)
+    assert p["w"].dtype == jnp.bfloat16 and p["e"].dtype == jnp.bfloat16
+    assert not bool(jnp.all(w[0] == w[1]))
+    for i in range(3):
+        assert abs(float(jnp.std(w[i])) - 64 ** -0.5) < 0.2 * 64 ** -0.5
+    assert bool(jnp.all(p["n"] == 1))
+    assert abs(float(jnp.std(p["e"].astype(jnp.float32))) - 1.0) < 0.1

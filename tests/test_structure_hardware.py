@@ -84,3 +84,11 @@ def test_decode_steps_increase_datamove():
     llm0 = next(c for c in g0 if c.kind == "llm")
     llm7 = next(c for c in g7 if c.kind == "llm")
     assert llm7.datamove_bytes > 5 * llm0.datamove_bytes  # weight re-reads
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    from repro.core.hardware import CHIP_PEAKS, chip_peaks
+    assert chip_peaks("TPU v5 lite") is TPU_V5E
+    assert set(CHIP_PEAKS) == {"TPU v5 lite"}
+    with pytest.raises(KeyError, match="TPU v9"):
+        chip_peaks("TPU v9")

@@ -1,0 +1,135 @@
+"""Ahead-of-time compiles of every Pallas kernel for a described TPU v5e.
+
+Nothing runs: each test lowers a kernel at the widths the served models
+use and compiles it with the TPU compiler for a chip that is described,
+not attached — which refuses block shapes not aligned to the tiling, and
+kernels that need more fast memory than a core has, exactly as the chip
+would.  The topology is described inside a module-scoped fixture (never
+at import), and the persistent compilation cache is off around the
+compiles, since entries written for a described chip cannot be read back
+without one.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.activation_codec import ops as codec_ops
+from repro.kernels.decode_attention import ops as da_ops
+from repro.kernels.flash_attention import ops as fa_ops
+from repro.kernels.ssd_scan import ops as ssd_ops
+
+CUT = (273, 4096)        # one OpenVLA request's cut: 256 patches + 17 tokens
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - any failure: cannot describe
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            cc.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("name", ["int8_quantize", "int8_dequantize",
+                                  "int4_quantize", "int4_dequantize"])
+def test_codec_kernels_compile_at_cut_shape(one_chip, name):
+    R, D = CUT
+    nb = D // 128
+    cases = {
+        "int8_quantize": (lambda x: codec_ops.quantize(x, impl="pallas"),
+                          [((1, R, D), jnp.bfloat16)]),
+        "int8_dequantize": (
+            lambda q, s: codec_ops.dequantize(q, s, impl="pallas"),
+            [((1, R, D), jnp.int8), ((1, R, nb), jnp.float32)]),
+        "int4_quantize": (
+            lambda x: codec_ops.quantize_int4(x, impl="pallas"),
+            [((1, R, D), jnp.bfloat16)]),
+        "int4_dequantize": (
+            lambda p, s: codec_ops.dequantize_int4(p, s, impl="pallas"),
+            [((1, R, D // 2), jnp.int8), ((1, R, nb), jnp.float32)]),
+    }
+    fn, shapes = cases[name]
+    _compile(fn, one_chip, *shapes)
+
+
+def test_flash_attention_compiles_32_heads(one_chip):
+    shape = ((1, 512, 32, 128), jnp.bfloat16)
+    _compile(lambda q, k, v: fa_ops.flash_attention(q, k, v, impl="pallas"),
+             one_chip, shape, shape, shape)
+
+
+def test_decode_attention_compiles_2k_cache(one_chip):
+    kv = ((1, 32, 2048, 128), jnp.bfloat16)
+    _compile(lambda q, k, v, n: da_ops.decode_attention(q, k, v, n,
+                                                        impl="pallas"),
+             one_chip, ((1, 32, 128), jnp.bfloat16), kv, kv,
+             ((), jnp.int32))
+
+
+def test_ssd_scan_compiles_mamba2_widths(one_chip):
+    # mamba2-1.3b: d_inner 4096 = 64 heads x 64, state 128, chunk 256
+    B, T, H, P, N = 1, 512, 64, 64, 128
+    _compile(lambda x, dt, A, Bm, Cm: ssd_ops.ssd_scan(
+        x, dt, A, Bm, Cm, chunk=256, impl="pallas"), one_chip,
+        ((B, T, H, P), jnp.bfloat16), ((B, T, H), jnp.float32),
+        ((H,), jnp.float32), ((B, T, N), jnp.bfloat16),
+        ((B, T, N), jnp.bfloat16))
+
+
+def test_openvla_split_programs_fit_one_chip(one_chip):
+    """The served openvla-7b edge and cloud programs (int8 cut, published
+    widths and depth) compile for one v5e and fit its memory next to the
+    resident parameters, with temporaries far below one layer's weights
+    (a loop that copied weight slices or stacks would show here)."""
+    from repro.configs import get_config
+    from repro.models import build
+    from repro.models.sharding import shape_tree
+    from repro.runtime.partition import SplitPlan, VLASplitExecutor
+
+    cfg = get_config("openvla-7b")
+    Lv, R = cfg.vit_layers, cfg.n_patches + 17
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shape_tree(build(cfg).param_specs))
+    param_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(params))
+    ex = VLASplitExecutor(cfg, SplitPlan(Lv + 1, Lv + 2, codec="int8"))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    split = sds((), jnp.int32)
+    edge = ex._edge.lower(params, sds((1, cfg.n_patches, cfg.vit_dim),
+                                      jnp.bfloat16),
+                          sds((1, 17), jnp.int32), split).compile()
+    cloud = ex._cloud.lower(
+        params, {"q": sds((1, R, cfg.d_model), jnp.int8),
+                 "s": sds((1, R, cfg.d_model // 128), jnp.float32)},
+        split, sds((2,), jnp.uint32)).compile()
+    layer_bytes = 2 * (4 * cfg.d_model ** 2 + 3 * cfg.d_model * cfg.d_ff)
+    bytes_limit = 16_909_336_064        # what one v5e's memory_stats reports
+    for prog in (edge, cloud):
+        m = prog.memory_analysis()
+        assert m.temp_size_in_bytes < layer_bytes / 4
+        assert param_bytes + m.temp_size_in_bytes \
+            + m.output_size_in_bytes < bytes_limit

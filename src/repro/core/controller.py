@@ -20,6 +20,7 @@ import time
 from typing import List, Optional, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs.base import ModelConfig
 from .adjustment import (AdjustmentDecision, PlacementDecision, Thresholds,
@@ -235,56 +236,30 @@ class RoboECC:
 
     # ------------------------------------------------------------------ tick
     def tick(self, net: NetworkSim, adjust_enabled: bool = True) -> TickResult:
+        """One control step.  Its three stages are host spans on the
+        profiler's clock: ``roboecc/tick/forecast`` (the LSTM forecast and
+        its host sync), ``roboecc/tick/adjust`` (the ΔNB move and codec
+        resolution) and ``roboecc/tick/price`` (the modeled edge, cloud
+        and link latency at the next tick's bandwidth)."""
         bw_real = net.now_bps
         decision = None
         bw_pred = bw_real
         t0 = time.perf_counter()
         if adjust_enabled and self.predictor is not None:
-            window = net.window(self.predictor.cfg.window)
-            bw_pred = self.predictor.predict(window)
-            if self.multicut or self.streamed:
-                # the streamed single-cut controller also routes through
-                # the placement adjuster: its move set carries the chunk
-                # axis (pool2=None pins S2 = n, so cuts stay single)
-                decision = adjust_placement(
-                    self.graph, self.pool, self.placement, bw_pred, bw_real,
-                    self.thresholds, pool2=self.pool2,
-                    codecs=self.adjust_codecs,
-                    edge=self.edge_dev, cloud=self.cloud_dev,
-                    down_bw_factor=self.down_bw_factor,
-                    chunk_grid=self.chunk_grid if self.streamed else None,
-                    rtt_s=self.plan_rtt_s if self.streamed else 0.0,
-                    queue_hz=self.queue_hz, queue_cv2=self.queue_cv2,
-                    queue_service_scale=self.queue_service_scale)
-                self.placement = decision.placement
-                self.split = self.placement.primary_cut(len(self.graph))
-            else:
-                decision = adjust(self.graph, self.pool, self.split, bw_pred,
-                                  bw_real, self.thresholds,
-                                  codecs=self.adjust_codecs,
-                                  current_codec=self.codec.name
-                                  if self.codec else None,
-                                  edge=self.edge_dev, cloud=self.cloud_dev)
-                self.split = decision.split
-            if decision.codec is not None and (
-                    self.codec is None or decision.codec != self.codec.name):
-                # resolve within the adjuster's own axis, NOT the global
-                # registry — adjust_codecs may hold custom Codec instances
-                # (e.g. f32-raw variants) that a name lookup in CODECS
-                # would miss or silently swap for the bf16 defaults
-                self.codec = next(c for c in self.adjust_codecs
-                                  if c.name == decision.codec)
-            if not (self.multicut or self.streamed):
-                self.placement = PlacementPlan.single(
-                    self.split, self.codec.name if self.codec else None)
+            with TraceAnnotation("roboecc/tick/forecast"):
+                window = net.window(self.predictor.cfg.window)
+                bw_pred = self.predictor.predict(window)
+            with TraceAnnotation("roboecc/tick/adjust"):
+                decision = self._adjust(bw_pred, bw_real)
         overhead = time.perf_counter() - t0
-        # the *next* tick's bandwidth is what the transfer actually sees
-        net.step()
-        bw_serve = net.now_bps
-        if self.multicut or self.streamed:
-            e, c, t = self.placement_latency_at(bw_serve, net.rtt_s)
-        else:
-            e, c, t = self.latency_at(self.split, bw_serve, net.rtt_s)
+        with TraceAnnotation("roboecc/tick/price"):
+            # the *next* tick's bandwidth is what the transfer actually sees
+            net.step()
+            bw_serve = net.now_bps
+            if self.multicut or self.streamed:
+                e, c, t = self.placement_latency_at(bw_serve, net.rtt_s)
+            else:
+                e, c, t = self.latency_at(self.split, bw_serve, net.rtt_s)
         return TickResult(split=self.split, edge_s=e, cloud_s=c, net_s=t,
                           total_s=e + c + t + (overhead if adjust_enabled else 0.0),
                           decision=decision, adjust_overhead_s=overhead,
@@ -293,6 +268,47 @@ class RoboECC:
                           placement=self.placement,
                           n_chunks=self.placement.primary_chunks(
                               len(self.graph)))
+
+    def _adjust(self, bw_pred: float, bw_real: float
+                ) -> Union[AdjustmentDecision, PlacementDecision]:
+        """The ΔNB move for the forecast bandwidth: moves the cut(s) inside
+        their pools and may switch the codec."""
+        if self.multicut or self.streamed:
+            # the streamed single-cut controller also routes through
+            # the placement adjuster: its move set carries the chunk
+            # axis (pool2=None pins S2 = n, so cuts stay single)
+            decision = adjust_placement(
+                self.graph, self.pool, self.placement, bw_pred, bw_real,
+                self.thresholds, pool2=self.pool2,
+                codecs=self.adjust_codecs,
+                edge=self.edge_dev, cloud=self.cloud_dev,
+                down_bw_factor=self.down_bw_factor,
+                chunk_grid=self.chunk_grid if self.streamed else None,
+                rtt_s=self.plan_rtt_s if self.streamed else 0.0,
+                queue_hz=self.queue_hz, queue_cv2=self.queue_cv2,
+                queue_service_scale=self.queue_service_scale)
+            self.placement = decision.placement
+            self.split = self.placement.primary_cut(len(self.graph))
+        else:
+            decision = adjust(self.graph, self.pool, self.split, bw_pred,
+                              bw_real, self.thresholds,
+                              codecs=self.adjust_codecs,
+                              current_codec=self.codec.name
+                              if self.codec else None,
+                              edge=self.edge_dev, cloud=self.cloud_dev)
+            self.split = decision.split
+        if decision.codec is not None and (
+                self.codec is None or decision.codec != self.codec.name):
+            # resolve within the adjuster's own axis, NOT the global
+            # registry — adjust_codecs may hold custom Codec instances
+            # (e.g. f32-raw variants) that a name lookup in CODECS
+            # would miss or silently swap for the bf16 defaults
+            self.codec = next(c for c in self.adjust_codecs
+                              if c.name == decision.codec)
+        if not (self.multicut or self.streamed):
+            self.placement = PlacementPlan.single(
+                self.split, self.codec.name if self.codec else None)
+        return decision
 
     # --------------------------------------------------------- scene drift
     def observe_change_frac(self, measured_frac: float, *,

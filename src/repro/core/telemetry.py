@@ -47,7 +47,7 @@ __all__ = [
 class Span:
     """One timed stage on one lane.  ``lane`` names the track the span
     renders on (``robot:<arch>`` cohorts, ``replica:<name>``,
-    ``proc:<process>``, ``executor:<tier>``); ``req`` ties the stages of
+    ``proc:<process>``); ``req`` ties the stages of
     one request together across lanes (-1 = unaffiliated)."""
     name: str                 # stage kind: "edge", "uplink", "queue", ...
     cat: str                  # trace category: "request", "cloud", "wall"
@@ -106,12 +106,17 @@ class QuantileSketch:
     stay near-singleton (p99.9 keeps resolution) while the middle
     compresses.  ``k`` spans δ/2 units over [0, 1], which hard-caps the
     merged centroid count at ``δ/2 + 2`` — memory is O(max_centroids)
-    regardless of stream length.  No RNG, so identical streams give
-    identical sketches."""
+    regardless of stream length.  Equal values always share one centroid,
+    and while there are at most ``max_centroids`` distinct values nothing
+    else merges, so a stream of few distinct values is held exactly.
+    Each centroid also keeps the smallest and largest value it holds, and
+    a query reads the buffer without merging it.  No RNG, so identical
+    streams give identical sketches."""
 
     def __init__(self, max_centroids: int = 128):
         self.max_centroids = max(8, int(max_centroids))
-        self._cent: List[Tuple[float, float]] = []   # (mean, weight) sorted
+        # (mean, weight, lo, hi) sorted by mean
+        self._cent: List[Tuple[float, float, float, float]] = []
         self._buf: List[float] = []
         self.count = 0
         self.sum = 0.0
@@ -138,26 +143,42 @@ class QuantileSketch:
         return self.max_centroids / (2.0 * math.pi) \
             * math.asin(2.0 * min(1.0, max(0.0, q)) - 1.0)
 
+    def _collapsed(self) -> List[Tuple[float, float, float, float]]:
+        """The centroids and the buffered values sorted by mean, each run
+        of equal values as one centroid."""
+        items = self._cent + [(x, 1.0, x, x) for x in self._buf]
+        items.sort(key=lambda c: c[0])
+        cents: List[Tuple[float, float, float, float]] = []
+        for c in items:
+            last = cents[-1] if cents else None
+            if last and last[2] == last[3] == c[2] == c[3]:
+                cents[-1] = (c[0], last[1] + c[1], c[2], c[3])
+            else:
+                cents.append(c)
+        return cents
+
     def _compress(self) -> None:
-        items = self._cent + [(x, 1.0) for x in self._buf]
+        cents = self._collapsed()
         self._buf = []
-        if not items:
+        if len(cents) <= self.max_centroids:
+            self._cent = cents
             return
-        items.sort(key=lambda mw: mw[0])
-        total = sum(w for _, w in items)
-        out: List[Tuple[float, float]] = []
+        total = sum(c[1] for c in cents)
+        out: List[Tuple[float, float, float, float]] = []
         cum = 0.0                      # weight strictly before the open centroid
         k_lo = self._k(0.0)
-        c_sum, c_w = items[0][0] * items[0][1], items[0][1]
-        for m, w in items[1:]:
+        c_sum, c_w = cents[0][0] * cents[0][1], cents[0][1]
+        c_lo, c_hi = cents[0][2], cents[0][3]
+        for m, w, lo, hi in cents[1:]:
             if self._k((cum + c_w + w) / total) - k_lo > 1.0:
-                out.append((c_sum / c_w, c_w))
+                out.append((c_sum / c_w, c_w, c_lo, c_hi))
                 cum += c_w
                 k_lo = self._k(cum / total)
-                c_sum, c_w = 0.0, 0.0
+                c_sum, c_w, c_lo, c_hi = 0.0, 0.0, lo, hi
             c_sum += m * w
             c_w += w
-        out.append((c_sum / c_w, c_w))
+            c_lo, c_hi = min(c_lo, lo), max(c_hi, hi)
+        out.append((c_sum / c_w, c_w, c_lo, c_hi))
         self._cent = out
 
     @property
@@ -165,24 +186,34 @@ class QuantileSketch:
         return len(self._cent) + len(self._buf)
 
     def quantile(self, q: float) -> float:
-        """Estimate the q-quantile (0 <= q <= 1) by linear interpolation
-        across centroid midpoints, anchored at the exact min/max."""
+        """Estimate the q-quantile (0 <= q <= 1) by linear interpolation,
+        as ``numpy.quantile`` does between the ranks 0 .. count-1 at the
+        target rank ``q * (count - 1)``.  A centroid of ``w`` values over
+        ranks ``c .. c+w-1`` puts its smallest value at ``c``, its largest
+        at ``c+w-1`` and the mean of the rest midway, which is exact up to
+        three values and for equal values; the estimate never falls as
+        ``q`` grows."""
         if self.count == 0:
             return math.nan
-        self._compress()
-        cents = self._cent
         if q <= 0.0:
             return self.min
         if q >= 1.0:
             return self.max
-        target = q * self.count
-        # midpoint positions: centroid i sits at cum_before + w_i / 2
-        pts = [(0.0, self.min)]
+        target = q * (self.count - 1)
+        pts: List[Tuple[float, float]] = []
         cum = 0.0
-        for m, w in cents:
-            pts.append((cum + w / 2.0, m))
+        for m, w, lo, hi in self._collapsed():
+            pts.append((cum, lo))
+            if w > 2.0:
+                rest = (m * w - lo - hi) / (w - 2.0)
+                pts.append((cum + (w - 1.0) / 2.0, min(hi, max(lo, rest))))
+            if w > 1.0:
+                pts.append((cum + w - 1.0, hi))
             cum += w
-        pts.append((float(self.count), self.max))
+        top = -math.inf
+        for k, (p, v) in enumerate(pts):
+            top = max(top, v)
+            pts[k] = (p, top)
         for k in range(1, len(pts)):
             p1, v1 = pts[k]
             if target <= p1:
@@ -190,7 +221,10 @@ class QuantileSketch:
                 if p1 <= p0:
                     return v1
                 f = (target - p0) / (p1 - p0)
-                return v0 + f * (v1 - v0)
+                # numpy.quantile's rounding: from the nearer end
+                if f >= 0.5:
+                    return v1 - (v1 - v0) * (1.0 - f)
+                return v0 + (v1 - v0) * f
         return self.max
 
     @property
@@ -364,11 +398,6 @@ class FlightRecorder:
         return self._cont.pop(rid, None)
 
     # ------------------------------------------------------------ recording
-    def record_span(self, span: Span) -> None:
-        """Offer one free-standing span (e.g. executor wall-clock stages
-        from ``runtime/partition.py``) to the reservoir."""
-        self.spans.offer([span])
-
     def record_request(self, *, req: int, lane: str, t0_s: float,
                        edge_s: float, uplink_s: float, queue_s: float,
                        service_s: float, down_s: float, total_s: float,

@@ -133,7 +133,7 @@ class Served:
 def serve_request(ex, params, inputs: Tuple[jax.Array, ...], split: int,
                   key: Optional[jax.Array] = None) -> Served:
     """One co-inference, timed on the host clock until every output is
-    on the device."""
+    on the device; the wait is the host span ``roboecc/serve/wait``."""
     t0 = time.perf_counter()
     if isinstance(ex, VLASplitExecutor):
         out, logits, payload = ex.run(params, *inputs, split, key,
@@ -141,7 +141,8 @@ def serve_request(ex, params, inputs: Tuple[jax.Array, ...], split: int,
     else:
         out, payload = ex.run(params, *inputs, split)
         logits = out
-    jax.block_until_ready((out, logits, payload))
+    with jax.profiler.TraceAnnotation("roboecc/serve/wait"):
+        jax.block_until_ready((out, logits, payload))
     return Served(out, logits, payload, time.perf_counter() - t0)
 
 

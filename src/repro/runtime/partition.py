@@ -22,6 +22,13 @@ inference).  VLA workloads re-prefill every action step (the camera image
 changes), so caches never need to migrate across the cut — this matches the
 paper's setting, where adjustment happens between inferences.
 
+Tracing: each tier's work runs under the ``jax.named_scope`` names in
+``SCOPES``, which the compiled programs keep as ``op_name`` metadata, and
+``run`` dispatches each tier under a ``roboecc/serve/*`` host span
+(``jax.profiler.TraceAnnotation``).  Both show only in a profiler trace;
+neither changes the compiled work, and with no profiler session a span
+costs a few hundred nanoseconds of host time.
+
 The cut activation is optionally shipped through the int8 or packed-int4
 activation codec (kernels/activation_codec) — 2x / ~3.8x fewer wire bytes.
 The planner-side price of each format (wire factor + encode/decode compute)
@@ -55,16 +62,15 @@ data-dependent shape logic), outside every jitted forward.
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.pipeline import chunk_sizes
-from ..core.telemetry import Span
 from ..kernels.activation_codec import ops as codec
 from ..models import transformer as T
 from ..models import vla as V
@@ -73,16 +79,12 @@ from ..models.transformer import block_forward
 
 Tree = Any
 
-
-def _record_exec_spans(recorder, t0: float, t1: float, t2: float) -> None:
-    """Two wall-clock spans — edge forward, then cloud forward (+ edge
-    tail for two-pool plans) — on the ``executor:*`` lanes.  Host
-    ``perf_counter`` time, so the trace mixes with the simulator's model
-    time only by lane, never by clock."""
-    recorder.record_span(Span(name="edge_fwd", cat="executor", t0_s=t0,
-                              dur_s=t1 - t0, lane="executor:edge"))
-    recorder.record_span(Span(name="cloud_fwd", cat="executor", t0_s=t1,
-                              dur_s=t2 - t1, lane="executor:cloud"))
+# ``jax.named_scope`` names around each tier's work, read back from the
+# compiled programs' ``op_name`` metadata to split a tier's device time:
+# the ViT, text embed and concat (VLA edge); the block loop; the cut's
+# encode and decode; the final norm and action or LM head.
+SCOPES = ("vision", "trunk", "encode", "decode", "head")
+VISION, TRUNK, ENCODE, DECODE, HEAD = SCOPES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -181,7 +183,8 @@ def encode_activation(x: jax.Array, wire_codec):
             raise ValueError(
                 f"int4 codec needs last dim % 256 == 0, got {x.shape}; "
                 "use int8 (and plan with the int8 codec) instead")
-        p, s = codec.quantize_int4(x)
+        with jax.named_scope(ENCODE):
+            p, s = codec.quantize_int4(x)
         return {"q4": p, "s": s}
     if wire_codec not in ("int8", True):
         # refuse rather than silently ship a different format than the
@@ -189,19 +192,21 @@ def encode_activation(x: jax.Array, wire_codec):
         # plane here yet)
         raise ValueError(f"no data-plane codec {wire_codec!r}; "
                          "have '', 'int8', 'int4'")
-    q, s = codec.quantize(x, block=_codec_block(x.shape[-1]))
+    with jax.named_scope(ENCODE):
+        q, s = codec.quantize(x, block=_codec_block(x.shape[-1]))
     return {"q": q, "s": s}
 
 
 def decode_activation(payload: Dict, dtype=jnp.bfloat16) -> jax.Array:
     if "x" in payload:
         return payload["x"]
-    if "q4" in payload:
-        return codec.dequantize_int4(payload["q4"], payload["s"],
-                                     jnp.dtype(dtype))
-    q, s = payload["q"], payload["s"]
-    return codec.dequantize(q, s, jnp.dtype(dtype),
-                            block=q.shape[-1] // s.shape[-1])
+    with jax.named_scope(DECODE):
+        if "q4" in payload:
+            return codec.dequantize_int4(payload["q4"], payload["s"],
+                                         jnp.dtype(dtype))
+        q, s = payload["q"], payload["s"]
+        return codec.dequantize(q, s, jnp.dtype(dtype),
+                                block=q.shape[-1] // s.shape[-1])
 
 
 def payload_bytes(payload: Dict) -> int:
@@ -401,12 +406,18 @@ class LMSplitExecutor:
         """Blocks ``[lo, hi)`` across the dense/MoE layer groups; either
         bound may be a traced cut."""
         off = 0
-        for name, n, is_moe in T._groups(self.cfg):
-            x = _run_blocks(self.cfg, params[name], x, positions,
-                            _clip(lo - off, 0, n), _clip(hi - off, 0, n),
-                            is_moe=is_moe)
-            off += n
+        with jax.named_scope(TRUNK):
+            for name, n, is_moe in T._groups(self.cfg):
+                x = _run_blocks(self.cfg, params[name], x, positions,
+                                _clip(lo - off, 0, n), _clip(hi - off, 0, n),
+                                is_moe=is_moe)
+                off += n
         return x
+
+    def _head(self, params, x):
+        """Final norm + LM head."""
+        with jax.named_scope(HEAD):
+            return T.lm_logits(self.cfg, params, x)
 
     # -- edge side: embed + blocks [0, split)
     def _edge_fwd(self, params, tokens, split):
@@ -422,7 +433,7 @@ class LMSplitExecutor:
         x = decode_activation(payload, cfg.dtype)
         positions = jnp.arange(x.shape[1])
         x = self._span(params, x, positions, split, cfg.n_layers)
-        return T.lm_logits(cfg, params, x)
+        return self._head(params, x)
 
     # -- cloud side (two-pool): blocks [split, split2)
     def _cloud_mid_fwd(self, params, payload, split, split2):
@@ -438,37 +449,27 @@ class LMSplitExecutor:
         x = decode_activation(payload, cfg.dtype)
         positions = jnp.arange(x.shape[1])
         x = self._span(params, x, positions, split2, cfg.n_layers)
-        return T.lm_logits(cfg, params, x)
+        return self._head(params, x)
 
     # -- public API
     def run(self, params, tokens, split: int,
-            split2: Optional[int] = None, recorder=None):
-        """One co-inference.  Single-pool plans return
-        ``(logits, uplink_payload)``; two-pool plans take the second cut
-        ``split2`` and return ``(logits, {"up": ..., "down": ...})`` — the
-        logits computed on the edge tail.  With a ``FlightRecorder``
-        passed as ``recorder``, emits wall-clock edge/cloud spans (forces
-        device sync at the cut, so only pass one when tracing)."""
-        split = jnp.int32(self.plan.clamp(split))
-        t0 = time.perf_counter() if recorder is not None else 0.0
-        payload = self._edge(params, tokens, split)
-        t1 = 0.0
-        if recorder is not None:
-            jax.block_until_ready(payload)
-            t1 = time.perf_counter()
-        if not self.plan.two_pool:
-            logits = self._cloud(params, payload, split)
-            if recorder is not None:
-                jax.block_until_ready(logits)
-                _record_exec_spans(recorder, t0, t1, time.perf_counter())
-            return logits, payload
-        split2 = jnp.int32(self.plan.clamp2(
-            split2 if split2 is not None else self.plan.pool2_end))
-        down = self._cloud_mid(params, payload, split, split2)
-        logits = self._tail(params, down, split2)
-        if recorder is not None:
-            jax.block_until_ready(logits)
-            _record_exec_spans(recorder, t0, t1, time.perf_counter())
+            split2: Optional[int] = None):
+        """One co-inference, dispatched without waiting for the device.
+        Single-pool plans return ``(logits, uplink_payload)``; two-pool
+        plans take the second cut ``split2`` and return ``(logits, {"up":
+        ..., "down": ...})`` — the logits computed on the edge tail.  The
+        host spans ``roboecc/serve/edge`` and ``roboecc/serve/cloud``
+        cover each tier's dispatch on the profiler's clock."""
+        with TraceAnnotation("roboecc/serve/edge"):
+            split = jnp.int32(self.plan.clamp(split))
+            payload = self._edge(params, tokens, split)
+        with TraceAnnotation("roboecc/serve/cloud"):
+            if not self.plan.two_pool:
+                return self._cloud(params, payload, split), payload
+            split2 = jnp.int32(self.plan.clamp2(
+                split2 if split2 is not None else self.plan.pool2_end))
+            down = self._cloud_mid(params, payload, split, split2)
+            logits = self._tail(params, down, split2)
         return logits, {"up": payload, "down": down}
 
     def run_streamed(self, params, tokens, split: int, n_chunks: int,
@@ -532,8 +533,9 @@ class VLASplitExecutor:
         """LLM blocks ``[lo, hi)`` in graph indexing; either bound may be
         a traced cut."""
         Lv = self.cfg.vit_layers
-        return _run_blocks(self.cfg, params["blocks"], x, positions,
-                           lo - Lv, hi - Lv, is_moe=False)
+        with jax.named_scope(TRUNK):
+            return _run_blocks(self.cfg, params["blocks"], x, positions,
+                               lo - Lv, hi - Lv, is_moe=False)
 
     def _tail_slice(self) -> int:
         """Static downlink sequence length.  When pool 2 is degenerate at
@@ -557,19 +559,22 @@ class VLASplitExecutor:
         logits)``: the detok head's logits at the ``action_dim`` action
         positions, ``None`` for heads that decode no tokens."""
         cfg = self.cfg
-        h = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        if cfg.vla_action_head in ("detok", ""):
-            logits = V.detok_logits(cfg, params, h)
-            return V.detok_action(logits), logits
-        if cfg.vla_action_head == "dit":
-            return V.dit_sample(cfg, params["action"], h[:, -1], key), None
+        with jax.named_scope(HEAD):
+            h = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+            if cfg.vla_action_head in ("detok", ""):
+                logits = V.detok_logits(cfg, params, h)
+                return V.detok_action(logits), logits
+            if cfg.vla_action_head == "dit":
+                return V.dit_sample(cfg, params["action"], h[:, -1],
+                                    key), None
         raise NotImplementedError(cfg.vla_action_head)
 
     def _edge_fwd(self, params, patches, tokens, split):
         cfg = self.cfg
-        img = V.vit_encode(cfg, params["vit"], patches)
-        txt = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
-        x = jnp.concatenate([img, txt], axis=1)
+        with jax.named_scope(VISION):
+            img = V.vit_encode(cfg, params["vit"], patches)
+            txt = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
+            x = jnp.concatenate([img, txt], axis=1)
         positions = jnp.arange(x.shape[1])
         x = self._span(params, x, positions, cfg.vit_layers, split)
         return encode_activation(x, self.plan.wire_codec)
@@ -604,34 +609,26 @@ class VLASplitExecutor:
 
     def run(self, params, patches, tokens, split: int,
             key: Optional[jax.Array] = None,
-            split2: Optional[int] = None, recorder=None,
-            return_logits: bool = False):
-        """One co-inference.  Single-pool plans return
-        ``(action, uplink_payload)``; two-pool plans take the second cut
-        ``split2`` and return ``(action, {"up": ..., "down": ...})`` with
-        the action decoded on the edge tail.  ``return_logits`` inserts
-        the detok head's action-position logits (``None`` for other
-        heads): ``(action, logits, payload)``.  ``recorder`` as in
-        ``LMSplitExecutor.run``."""
-        split = jnp.int32(self.plan.clamp(split))
-        t0 = time.perf_counter() if recorder is not None else 0.0
-        payload = self._edge(params, patches, tokens, split)
-        t1 = 0.0
-        if recorder is not None:
-            jax.block_until_ready(payload)
-            t1 = time.perf_counter()
-        key = key if key is not None else jax.random.PRNGKey(0)
-        if not self.plan.two_pool:
-            action, logits = self._cloud(params, payload, split, key)
-        else:
-            split2 = jnp.int32(self.plan.clamp2(
-                split2 if split2 is not None else self.plan.pool2_end))
-            down = self._cloud_mid(params, payload, split, split2)
-            action, logits = self._tail(params, down, split2, key)
-            payload = {"up": payload, "down": down}
-        if recorder is not None:
-            jax.block_until_ready(action)
-            _record_exec_spans(recorder, t0, t1, time.perf_counter())
+            split2: Optional[int] = None, return_logits: bool = False):
+        """One co-inference, dispatched as in ``LMSplitExecutor.run``.
+        Single-pool plans return ``(action, uplink_payload)``; two-pool
+        plans take the second cut ``split2`` and return ``(action, {"up":
+        ..., "down": ...})`` with the action decoded on the edge tail.
+        ``return_logits`` inserts the detok head's action-position logits
+        (``None`` for other heads): ``(action, logits, payload)``."""
+        with TraceAnnotation("roboecc/serve/edge"):
+            split = jnp.int32(self.plan.clamp(split))
+            payload = self._edge(params, patches, tokens, split)
+        with TraceAnnotation("roboecc/serve/cloud"):
+            key = key if key is not None else jax.random.PRNGKey(0)
+            if not self.plan.two_pool:
+                action, logits = self._cloud(params, payload, split, key)
+            else:
+                split2 = jnp.int32(self.plan.clamp2(
+                    split2 if split2 is not None else self.plan.pool2_end))
+                down = self._cloud_mid(params, payload, split, split2)
+                action, logits = self._tail(params, down, split2, key)
+                payload = {"up": payload, "down": down}
         if return_logits:
             return action, logits, payload
         return action, payload

@@ -3,7 +3,7 @@
 Renders the span groups a ``core/telemetry.FlightRecorder`` kept into
 the Trace Event Format that ``chrome://tracing`` and Perfetto load
 directly: one *process* row per lane family (robot cohorts, cloud
-replicas, open-loop arrival processes, executor wall-clock), one
+replicas, open-loop arrival processes), one
 *thread* row per lane, ``"X"`` complete events for spans (microsecond
 ``ts``/``dur``) and ``"M"`` metadata events naming the rows.  The
 export walks only the reservoir-kept groups, so writing a trace of a
@@ -21,11 +21,10 @@ __all__ = ["chrome_trace", "export_chrome_trace"]
 # lane family (the prefix before ":") -> Chrome pid; unknown families
 # group under "other".  Perfetto sorts rows by pid, so this fixes the
 # top-to-bottom reading order of the trace.
-_FAMILY_PIDS = {"robot": 1, "proc": 2, "replica": 3, "executor": 4}
+_FAMILY_PIDS = {"robot": 1, "proc": 2, "replica": 3}
 _OTHER_PID = 9
 _FAMILY_NAMES = {1: "robot cohorts", 2: "arrival processes",
-                 3: "cloud replicas", 4: "executor wall-clock",
-                 _OTHER_PID: "other"}
+                 3: "cloud replicas", _OTHER_PID: "other"}
 
 
 def _lane_pid(lane: str) -> int:

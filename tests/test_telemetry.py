@@ -60,6 +60,36 @@ def test_sketch_seeded_sweeps():
     _check_sketch_accuracy([1.0, 1.0, 1.0, 1.0])
 
 
+@pytest.mark.parametrize("xs", [
+    [0.0, 1.0], [3.0, -2.0, 7.5], [5.0, 1.0, 1.0, 9.0, 4.0],
+    [0.0] * 9 + [1.0] * 3,
+    [0.0, 0.5, 0.75, 1.0, 2.0, 3.0, 6.0, 7.0, 8.0, 9.0, 10.0],
+    [-40009.0, -40008.0, -40005.0, -40004.0, -40003.0, -40002.0, -9841.0,
+     -8004.0, -8003.0] + [-1.0] * 3 + [0.0] * 24,
+    [float(x * x) for x in range(-12, 13)]])
+def test_sketch_small_samples_match_numpy(xs):
+    """Samples of up to ``2 * max_centroids - 1`` values, or of at most
+    ``max_centroids`` distinct ones, interpolate as ``np.quantile``."""
+    sk = QuantileSketch(16)
+    sk.extend(xs)
+    for q in (0.01, 0.1, 0.25, 0.5, 0.75, 0.9, 0.99):
+        assert sk.quantile(q) == pytest.approx(float(np.quantile(xs, q)),
+                                               abs=1e-12)
+
+
+def test_sketch_few_distinct_values_stay_exact():
+    """A long stream of few distinct values keeps one exact centroid per
+    value through every merge."""
+    rng = np.random.default_rng(5)
+    xs = rng.choice([-3.0, 0.0, 0.5, 2.0, 1e4], size=20_000,
+                    p=[0.1, 0.6, 0.1, 0.15, 0.05])
+    sk = QuantileSketch(16)
+    sk.extend(xs)
+    assert sk.n_centroids <= 2 * 16
+    for q in (0.01, 0.05, 0.1, 0.25, 0.5, 0.69, 0.7, 0.75, 0.95, 0.99):
+        assert sk.quantile(q) == float(np.quantile(xs, q))
+
+
 def test_sketch_empty_and_tails():
     sk = QuantileSketch()
     assert math.isnan(sk.quantile(0.5)) and math.isnan(sk.mean)

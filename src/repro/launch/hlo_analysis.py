@@ -14,10 +14,15 @@ ring wire factor:
 Collectives inside ``while`` bodies (e.g. a microbatch scan) are multiplied
 by the loop trip count when it is statically parseable; the dry-run unrolls
 layers so in practice whiles only appear when explicitly requested.
+
+``loop_weight_copies`` / ``loop_stack_readers`` read how a layer loop's
+body touches its stacked weights: in place by the dots, or through a
+per-step copy of the layer.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 import re
 from typing import Dict, List, Optional, Tuple
 
@@ -192,3 +197,118 @@ def summarize(ops: List[CollectiveOp]) -> Dict:
     total_16 = sum(d["wire_bytes_bf16"] for d in by_kind.values())
     return {"by_kind": by_kind, "total_wire_bytes_per_device": total_wire,
             "total_wire_bytes_bf16_per_device": total_16, "n_ops": len(ops)}
+
+
+# ------------------------------------------------- stacked weights in loops
+# A layer loop indexes each step's weights out of stacks of shape
+# (L, ...).  A dot can read its layer in place, the index fused into it;
+# anything else that touches a stack writes a layer-sized buffer (a slice,
+# a copy, a relayout) every step.  These read the compiled text.
+_PLUMBING = frozenset(("bitcast", "get-tuple-element", "tuple", "parameter",
+                       "constant"))
+
+
+def _group_end(s: str, i: int) -> int:
+    """Index just past the parenthesised group that opens at ``s[i]``."""
+    depth = 0
+    for j in range(i, len(s)):
+        depth += {"(": 1, ")": -1}.get(s[j], 0)
+        if depth == 0:
+            return j + 1
+    return len(s)
+
+
+def _instructions(lines: List[str]):
+    """(name, result type, opcode, operand names, attributes) per line."""
+    for line in lines:
+        m = re.match(r"\s+(?:ROOT\s+)?%([\w.\-]+) = ", line)
+        if not m:
+            continue
+        rest = line[m.end():]
+        end = _group_end(rest, 0) if rest.startswith("(") else rest.find(" ")
+        result, rest = rest[:end], rest[end:].lstrip()
+        op = re.match(r"([\w\-]+)\(", rest)
+        if not op:
+            continue
+        close = _group_end(rest, op.end() - 1)
+        yield (m.group(1), result, op.group(1),
+               re.findall(r"%([\w.\-]+)", rest[op.end():close]), rest[close:])
+
+
+def while_bodies(hlo_text: str) -> Dict[str, list]:
+    """Each while loop's body: the instructions of its own computation,
+    not of the computations it fuses or calls."""
+    names = set(re.findall(r"while\([^)]*\), condition=%?[\w.\-]+, "
+                           r"body=%?([\w.\-]+)", hlo_text))
+    comps: Dict[str, List[str]] = {}
+    current = None
+    for line in hlo_text.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if m:
+            current = m.group(1) if m.group(1) in names else None
+            comps[current] = []
+        elif current is not None and line.startswith("}"):
+            current = None
+        elif current is not None:
+            comps[current].append(line)
+    comps.pop(None, None)
+    return {n: list(_instructions(ls)) for n, ls in comps.items()}
+
+
+def _elements(result: str) -> Optional[int]:
+    """Element count of the first array in a result type."""
+    m = _SHAPE_RE.search(result)
+    if not m:
+        return None
+    n = 1
+    for d in filter(None, m.group(2).split(",")):
+        n *= int(d)
+    return n
+
+
+def _is_dot(op: str, attrs: str) -> bool:
+    return op in ("dot", "convolution") or (
+        op == "fusion" and "kind=kOutput" in attrs)
+
+
+def loop_weight_copies(hlo_text: str, stacks) -> List[str]:
+    """Instructions of a while body that copy a stacked weight.
+
+    ``stacks``: the shapes ``(L, ...)`` of the stacked weights.  Follows
+    each loop-carried stack through the body and returns every
+    instruction, other than a dot and plumbing (bitcasts, tuples), that
+    writes a buffer of one layer's or of the whole stack's size from it:
+    a per-step slice, copy or relayout.  A loop whose dots read their
+    layer in place returns none."""
+    layer = {math.prod(s[1:]) for s in stacks}
+    whole = {math.prod(s) for s in stacks}
+    copies = []
+    for body in while_bodies(hlo_text).values():
+        derived = set()
+        for name, result, op, operands, attrs in body:
+            n = _elements(result)
+            if op == "get-tuple-element" and n in whole:
+                derived.add(name)
+            elif derived.intersection(operands) and n in layer | whole \
+                    and not _is_dot(op, attrs):
+                derived.add(name)
+                if op not in _PLUMBING:
+                    copies.append(name)
+    return copies
+
+
+def loop_stack_readers(hlo_text: str, stacks) -> List[Tuple[str, str]]:
+    """``(instruction, kind)`` for each instruction of a while body that
+    takes a loop-carried stacked weight as an operand: a fusion's kind
+    (``kOutput``: a dot with the layer index fused in; ``kLoop``: a slice
+    written out) or else the opcode.  The loop's own carry is left out."""
+    whole = {math.prod(s) for s in stacks}
+    out = []
+    for body in while_bodies(hlo_text).values():
+        carried = {name for name, result, op, _, _ in body
+                   if op == "get-tuple-element" and _elements(result) in whole}
+        for name, _, op, operands, attrs in body:
+            if op != "tuple" and carried.intersection(operands):
+                kind = re.search(r"kind=(\w+)", attrs)
+                out.append((name, kind.group(1) if kind else op))
+    return out

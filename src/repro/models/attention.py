@@ -172,12 +172,22 @@ def _out_proj(out2d: jax.Array, wo: jax.Array) -> jax.Array:
 
 # ============================================================== GQA forward
 def _qkv(cfg, p, x):
+    """Project ``x`` to q ``(B,S,H,hd)`` and k, v ``(B,S,KV,hd)``.
+
+    The projections leave their dots flat, ``(B,S,H*hd)``, as the weights
+    lay them out; the head split, rope and the head-major transposes
+    downstream run on these activations.  The barrier pins that order:
+    without it the compiler gives the dots a head-major output layout and
+    meets it by relayouting the weight, which inside a layer loop is a
+    copy of the layer's weight out of the stack, and a transpose of it,
+    every step (DESIGN §7)."""
     hd, H, KV = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     q = dense(x, p["wq"])
     k = dense(x, p["wk"])
     v = dense(x, p["wv"])
     if "bq" in p:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+    q, k, v = jax.lax.optimization_barrier((q, k, v))
     B, S = x.shape[:2]
     q = shard(q.reshape(B, S, H, hd), "batch", "seq", "act_heads", None)
     k = k.reshape(B, S, KV, hd)

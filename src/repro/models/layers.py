@@ -31,18 +31,31 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, D) or (B, S, D); positions: (S,)."""
+    """x: (B, S, H, D) or (B, S, D); positions: (S,).
+
+    Rotate-half rope in float32, ``x * cos + rotate_half(x) * sin`` with
+    ``rotate_half(x) = [-x2, x1]``.  The rotation is a product with a fixed
+    signed permutation, exact (one nonzero term per output, at HIGHEST
+    precision).  On a TPU it runs as one small dot that reads ``x`` in
+    bf16 and writes the head-major result, where splitting ``x`` into
+    halves along its minor dimension costs a float32 copy of ``x`` and two
+    more passes over it (DESIGN §7)."""
     dt = x.dtype
     d = x.shape[-1]
     freqs = rope_freqs(d, theta)                            # (D/2,)
     ang = positions[:, None].astype(jnp.float32) * freqs    # (S, D/2)
+    ang = jnp.concatenate([ang, ang], axis=-1)              # (S, D)
     if x.ndim == 4:
         ang = ang[None, :, None, :]
     else:
         ang = ang[None, :, :]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    eye = jnp.eye(d // 2, dtype=dt)
+    zero = jnp.zeros_like(eye)
+    rot = jnp.block([[zero, eye], [-eye, zero]])
+    x_rot = jnp.einsum("...d,de->...e", x, rot,
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+    out = x.astype(jnp.float32) * jnp.cos(ang) + x_rot * jnp.sin(ang)
     return out.astype(dt)
 
 

@@ -146,7 +146,8 @@ def _run_blocks(cfg, blocks: Tree, x: jax.Array, positions, lo, hi, *,
     """Run blocks ``[lo, hi)`` of the stacked ``blocks`` tree.
 
     One loop step per block, each indexing its layer's weights out of the
-    stack, so the program never copies more than one layer.  Either bound
+    stack; the projection dots read that layer in place, so no step
+    copies a weight (``models/attention._qkv``).  Either bound
     may be traced — a dynamic cut is a loop bound, so moving it inside its
     pool recompiles nothing and no block runs on both tiers."""
     def body(i, h):

@@ -4,6 +4,7 @@ import re
 import pytest
 
 from repro.launch.hlo_analysis import (_parse_trip_count, _shape_bytes,
+                                       loop_stack_readers, loop_weight_copies,
                                        parse_collectives, summarize)
 
 
@@ -83,3 +84,50 @@ def test_real_compiled_program():
     assert ops == []  # single-device: no collectives
     s = summarize(ops)
     assert s["total_wire_bytes_per_device"] == 0
+
+
+# A layer loop over stacks of four 8x8 weights: ``wq`` is sliced out and
+# relayouted before its dot, ``wo`` is read in place by its dot.  ``a`` is
+# an activation the size of a layer that no weight flows into.
+LOOP = """
+HloModule jit_loop
+
+%body (p: (s32[], bf16[4,8,8], bf16[4,8,8], bf16[8,8])) -> (s32[], bf16[4,8,8], bf16[4,8,8], bf16[8,8]) {
+  %p = (s32[], bf16[4,8,8]{2,1,0:T(8,128)(2,1)}, bf16[4,8,8]{2,1,0}, /*index=3*/bf16[8,8]{1,0}) parameter(0)
+  %i = s32[] get-tuple-element(%p), index=0
+  %wq = bf16[4,8,8]{2,1,0:T(8,128)(2,1)} get-tuple-element(%p), index=1
+  %wo = bf16[4,8,8]{2,1,0} get-tuple-element(%p), index=2
+  %x = bf16[8,8]{1,0} get-tuple-element(%p), index=3
+  %a = bf16[8,8]{1,0} add(%x, %x)
+  %s = bf16[1,8,8]{2,1,0:T(8,128)(2,1)S(1)} fusion(%wq, %i), kind=kLoop, calls=%slice
+  %c = bf16[1,8,8]{1,2,0} copy(%s)
+  %b = bf16[8,8]{0,1} bitcast(%c)
+  %q = bf16[8,8]{1,0} fusion(%a, %b), kind=kOutput, calls=%dot
+  %o = bf16[8,8]{1,0} fusion(%q, %wo, %i), kind=kOutput, calls=%dot
+  ROOT %t = (s32[], bf16[4,8,8]{2,1,0}, bf16[4,8,8]{2,1,0}, bf16[8,8]{1,0}) tuple(%i, %wq, %wo, %o)
+}
+
+%cond (p: (s32[], bf16[4,8,8], bf16[4,8,8], bf16[8,8])) -> pred[] {
+  %i = s32[] get-tuple-element(%p), index=0
+  %k = s32[] constant(4)
+  ROOT %lt = pred[] compare(%i, %k), direction=LT
+}
+
+ENTRY %main (w: bf16[4,8,8]) -> bf16[8,8] {
+  %s = bf16[1,8,8]{2,1,0} fusion(%w0, %z), kind=kLoop, calls=%slice
+  %w = (s32[], bf16[4,8,8], bf16[4,8,8], bf16[8,8]) while(%init), condition=%cond, body=%body
+  ROOT %r = bf16[8,8]{1,0} get-tuple-element(%w), index=3
+}
+"""
+
+
+def test_loop_weight_copies_follow_the_stacks():
+    # the slice and its relayout count; the bitcast, the dots, the
+    # activation of a layer's size and the slice outside the loop do not
+    assert loop_weight_copies(LOOP, [(4, 8, 8)] * 2) == ["s", "c"]
+    assert loop_weight_copies(LOOP, [(4, 8, 16)]) == []
+
+
+def test_loop_stack_readers_name_each_kind():
+    assert loop_stack_readers(LOOP, [(4, 8, 8)] * 2) == [("s", "kLoop"),
+                                                         ("o", "kOutput")]

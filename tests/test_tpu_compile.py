@@ -133,3 +133,47 @@ def test_openvla_split_programs_fit_one_chip(one_chip):
         assert m.temp_size_in_bytes < layer_bytes / 4
         assert param_bytes + m.temp_size_in_bytes \
             + m.output_size_in_bytes < bytes_limit
+
+
+@pytest.mark.parametrize("case", ["trunk_b1", "trunk_b4", "vit"])
+def test_layer_loops_read_stacked_weights_in_place(one_chip, case):
+    """Every projection dot in a layer loop reads its layer of the stacked
+    weights in place, the index fused into the dot: no step writes a
+    slice, copy or relayout of a weight.  Openvla-7b's trunk at published
+    widths and depth (one robot's 273 rows, and four robots'), and its
+    1024-wide ViT tower.  At a depth of two the stacks fit the chip's
+    vector memory and the compiler prefetches them, which a published
+    depth never allows, so the loops keep their depth (it costs no
+    compile time: the body compiles once)."""
+    from repro.configs import get_config
+    from repro.launch.hlo_analysis import (loop_stack_readers,
+                                           loop_weight_copies)
+    from repro.models import vla as V
+    from repro.models.sharding import shape_tree
+    from repro.models.transformer import dense_block_specs
+    from repro.runtime.partition import _run_blocks
+
+    cfg = get_config("openvla-7b")
+    R = cfg.n_patches + 17
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if case == "vit":
+        weights = shape_tree(V.vit_specs(cfg))
+        fn = lambda w, x: V.vit_encode(cfg, w, x)
+        args = (sds((1, cfg.n_patches, cfg.vit_dim)),)
+    else:
+        B = 1 if case == "trunk_b1" else 4
+        weights = shape_tree(dense_block_specs(cfg, cfg.n_layers))
+        fn = lambda w, x, lo: _run_blocks(cfg, w, x, jnp.arange(R), lo,
+                                          cfg.n_layers, is_moe=False)
+        args = (sds((B, R, cfg.d_model)), sds((), jnp.int32))
+    weights = jax.tree_util.tree_map(lambda s: sds(s.shape, s.dtype), weights)
+    text = jax.jit(fn).lower(weights, *args).compile().as_text()
+    stacks = [w.shape for w in jax.tree_util.tree_leaves(weights)
+              if w.ndim == 3]
+    assert loop_weight_copies(text, stacks) == []
+    readers = loop_stack_readers(text, stacks)
+    assert len(readers) >= 6
+    assert {kind for _, kind in readers} == {"kOutput"}, readers

@@ -1,7 +1,8 @@
 """Architecture registry: ``get_config("<arch-id>")`` returns a ModelConfig.
 
-The 10 assigned architectures (``--arch`` ids) plus the paper's own VLA
-models (openvla-7b, cogact-7b) used by the RoboECC experiments.
+The 10 assigned architectures (``--arch`` ids), the paper's own VLA
+models (openvla-7b, cogact-7b) used by the RoboECC experiments, and a VLA
+on Kimi-VL-A3B's MoE + MLA backbone (kimi-vl-a3b-vla).
 """
 from __future__ import annotations
 
@@ -19,6 +20,7 @@ from . import (
     zamba2_1_2b,
     openvla_7b,
     cogact_7b,
+    kimi_vl_a3b_vla,
 )
 
 ARCHS = {
@@ -35,9 +37,11 @@ ARCHS = {
     # paper's own evaluation models
     "openvla-7b": openvla_7b.CONFIG,
     "cogact-7b": cogact_7b.CONFIG,
+    "kimi-vl-a3b-vla": kimi_vl_a3b_vla.CONFIG,
 }
 
-ASSIGNED = tuple(k for k in ARCHS if k not in ("openvla-7b", "cogact-7b"))
+VLAS = ("openvla-7b", "cogact-7b", "kimi-vl-a3b-vla")
+ASSIGNED = tuple(k for k in ARCHS if k not in VLAS)
 
 
 def get_config(name: str) -> ModelConfig:

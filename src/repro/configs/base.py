@@ -50,6 +50,18 @@ class ModelConfig:
     moe_d_ff: int = 0
     moe_capacity_factor: float = 1.25
     first_dense_layers: int = 0     # deepseek: first k layers use dense FFN
+    # dropless routing over held experts [expert_start, expert_start +
+    # experts_held) (0: all n_experts); the router stays n_experts wide
+    moe_dropless: bool = False
+    experts_held: int = 0
+    expert_start: int = 0
+    # softmax | sigmoid (DeepSeek-V3: bias-corrected top-k selection,
+    # gates normalised over the top k and scaled by moe_routed_scale)
+    moe_scoring: str = "softmax"
+    moe_routed_scale: float = 1.0
+    # a dropless layer's grouped matmuls: xla | pallas | interpret
+    # (launch/serve picks pallas when it serves on a TPU)
+    moe_impl: str = "xla"
 
     # -- SSM (mamba2 / SSD) ---------------------------------------------------
     ssm_state: int = 0
@@ -75,6 +87,12 @@ class ModelConfig:
     vit_layers: int = 0
     vit_dim: int = 0
     n_patches: int = 0
+    vit_heads: int = 0              # 0 -> vit_dim // 64
+    vit_ff: int = 0                 # 0 -> 4 * vit_dim
+    # llama: RMSNorm, SwiGLU, rope over the patch index (the stand-in);
+    # siglip: LayerNorm, biases, tanh-GELU MLP, 2D rope over the grid
+    vit_block: str = "llama"
+    vit_merge: int = 1              # k x k pixel shuffle before the projector
     action_dim: int = 7
     action_horizon: int = 16
     diffusion_steps: int = 10
@@ -99,6 +117,11 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // max(self.n_heads, 1)
+
+    @property
+    def n_experts_held(self) -> int:
+        """Routed experts whose weights this program holds."""
+        return self.experts_held or self.n_experts
 
     @property
     def d_inner(self) -> int:
@@ -153,10 +176,9 @@ class ModelConfig:
                 total += n_x * (attn_params() + 2 * d)
         elif self.family == "moe":
             n_moe = nl - self.first_dense_layers
-            moe = self.n_experts * mlp_params(self.moe_d_ff) + d * self.n_experts
-            moe += self.n_shared_experts * mlp_params(self.moe_d_ff)
             total += nl * (attn_params() + 2 * d)
-            total += self.first_dense_layers * mlp_params(self.d_ff) + n_moe * moe
+            total += self.first_dense_layers * mlp_params(self.d_ff) \
+                + n_moe * self._moe_params(False)
         elif self.family == "ssm":
             total += nl * (self._mamba_params() + d)
         elif self.family == "hybrid":
@@ -167,11 +189,38 @@ class ModelConfig:
             dec = self.n_dec_layers * (2 * attn_params() + mlp_params(self.d_ff) + 3 * d)
             total += enc + dec
         elif self.family == "vla":
-            total += self.vit_layers * (4 * self.vit_dim ** 2 + 8 * self.vit_dim ** 2) \
-                + self.vit_dim * d
-            total += nl * (attn_params() + mlp_params(self.d_ff) + 2 * d)
+            total += self._vit_params()
+            if self.n_experts:
+                total += nl * (attn_params() + 2 * d) \
+                    + self.first_dense_layers * mlp_params(self.d_ff) \
+                    + (nl - self.first_dense_layers) * self._moe_params(True)
+            else:
+                total += nl * (attn_params() + mlp_params(self.d_ff) + 2 * d)
             total += self._action_head_params()
         return total
+
+    def _moe_params(self, held: bool) -> int:
+        """One MoE layer's FFN: the routed experts (only those held when
+        ``held``), the shared experts and the router."""
+        d, fe = self.d_model, self.moe_d_ff
+        n = self.n_experts_held if held else self.n_experts
+        out = (n + self.n_shared_experts) * 3 * d * fe + d * self.n_experts
+        if self.moe_scoring == "sigmoid":
+            out += self.n_experts                   # selection bias
+        return out
+
+    def _vit_params(self) -> int:
+        """Vision tower and projector.  The 64-wide stand-in counts as it
+        always has (four attention and two MLP matrices a block); a SigLIP
+        block with its biases, LayerNorms and the pixel-shuffle MLP
+        projector are counted exactly."""
+        dv, d = self.vit_dim, self.d_model
+        if self.vit_block != "siglip":
+            return self.vit_layers * 12 * dv ** 2 + dv * d
+        ff, k2 = self.vit_ff or 4 * dv, self.vit_merge ** 2
+        block = 4 * dv * dv + 4 * dv + 2 * dv * ff + ff + dv + 4 * dv
+        proj = 2 * dv + k2 * dv * (k2 * dv + 1) + k2 * dv * d + d
+        return self.n_patches * dv + self.vit_layers * block + 2 * dv + proj
 
     def _mamba_params(self) -> int:
         d, di, ns = self.d_model, self.d_inner, self.ssm_state
@@ -218,7 +267,9 @@ class ModelConfig:
         if self.n_experts:
             kw.update(n_experts=4, moe_top_k=2, moe_d_ff=64,
                       n_shared_experts=min(self.n_shared_experts, 1),
-                      first_dense_layers=min(self.first_dense_layers, 1))
+                      first_dense_layers=min(self.first_dense_layers, 1),
+                      experts_held=min(self.experts_held, 2),
+                      expert_start=0)
         if self.ssm_state:
             kw.update(ssm_state=16, ssm_headdim=16, ssm_chunk=32, d_model=64)
         if self.shared_attn_every:
@@ -231,6 +282,8 @@ class ModelConfig:
             kw.update(vit_layers=2, vit_dim=32, n_patches=16,
                       dit_layers=2, dit_dim=32, dit_heads=2,
                       diffusion_steps=2, action_horizon=4)
+            if self.vit_heads:
+                kw.update(vit_heads=2, vit_ff=64)
         return self.replace(**kw)
 
 

@@ -86,6 +86,29 @@ def _mla_flops(cfg: ModelConfig, S: int, T: int) -> float:
     return proj + attn
 
 
+def _mla_absorbed_flops(cfg: ModelConfig, S: int, T: int) -> float:
+    """MLA with W_kb absorbed into the query and W_vb applied after the
+    attention: scores and values over the 512-wide latent."""
+    d, H = cfg.d_model, cfg.n_heads
+    r, qn, qr, vd = cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    proj = 2 * S * d * H * (qn + qr) + 2 * S * d * (r + qr) \
+        + 2 * S * H * qn * r + 2 * S * H * r * vd + 2 * S * H * vd * d
+    attn = 2 * S * T * H * (r + qr) + 2 * S * T * H * r
+    return proj + attn
+
+
+def _held_moe_cost(cfg: ModelConfig, S: int):
+    """(flops, weight count, kind) of a MoE FFN held at a chip's share:
+    the router over all experts, the routed work at k / E of the rows on
+    each held expert, the shared experts; the held experts' weights."""
+    d, fe, E, k = cfg.d_model, cfg.moe_d_ff, cfg.n_experts, cfg.moe_top_k
+    held, shared = cfg.n_experts_held, cfg.n_shared_experts
+    flops = 2 * S * d * E + 2 * S * 3 * d * fe * (k * held / E + shared)
+    weights = (held + shared) * 3 * d * fe + d * E \
+        + (E if cfg.moe_scoring == "sigmoid" else 0)
+    return flops, weights, "moe"
+
+
 def _attn_weight_count(cfg: ModelConfig) -> float:
     d, hd = cfg.d_model, cfg.resolved_head_dim
     if cfg.use_mla:
@@ -146,14 +169,27 @@ def build_graph(cfg: ModelConfig, w: Workload = Workload()) -> List[LayerCost]:
         dv = cfg.vit_dim
         P = cfg.n_patches
         attn = 2 * P * dv * 4 * dv + 2 * P * P * dv * 2
-        mlp = 2 * P * 3 * (4 * dv) * dv  # ~GELU MLP ≈ 2*P*2*4dv*dv; use swiglu-equiv
-        wcount = 4 * dv * dv + 8 * dv * dv
+        if cfg.vit_block == "siglip":
+            # GELU MLP, biases and LayerNorms; k x k pixel shuffle into a
+            # Linear-GELU-Linear projector over P / k^2 tokens
+            ff, k2 = cfg.vit_ff or 4 * dv, cfg.vit_merge ** 2
+            mlp = 2 * P * 2 * ff * dv
+            wcount = 4 * dv * dv + 2 * dv * ff + ff + 9 * dv
+            P_out, dm = P // k2, k2 * dv
+            proj_f = 2 * P_out * dm * (dm + cfg.d_model)
+            proj_w = 2 * dv + dm * (dm + 1) + (dm + 1) * cfg.d_model
+        else:
+            # the stand-in's SwiGLU priced as two 4x matrices (as the
+            # pools and splits planned for it have always been)
+            mlp = 2 * P * 3 * (4 * dv) * dv
+            wcount = 4 * dv * dv + 8 * dv * dv
+            P_out = P
+            proj_f, proj_w = 2 * P * dv * cfg.d_model, dv * cfg.d_model
         for i in range(cfg.vit_layers):
             g.append(_block_cost(cfg, w, f"vit.{i}", "vit", attn + mlp,
                                  wcount, d_out=dv, s_out=P, decode_steps=0))
-        g.append(_block_cost(cfg, w, "vit.proj", "vit",
-                             2 * P * dv * cfg.d_model, dv * cfg.d_model,
-                             s_out=P, decode_steps=0))
+        g.append(_block_cost(cfg, w, "vit.proj", "vit", proj_f, proj_w,
+                             s_out=P_out, decode_steps=0))
 
     # ---- encoder (audio enc-dec) -----------------------------------------
     if cfg.family == "audio":
@@ -171,7 +207,18 @@ def build_graph(cfg: ModelConfig, w: Workload = Workload()) -> List[LayerCost]:
 
     # ---- S_bac / backbone blocks ----------------------------------------
     d = cfg.d_model
-    if cfg.family in ("dense", "vlm", "vla", "audio"):
+    if cfg.family == "vla" and cfg.n_experts:
+        attn_f = min(_mla_flops(cfg, S, T), _mla_absorbed_flops(cfg, S, T)) \
+            if cfg.use_mla else _attn_flops(cfg, S, T)
+        for i in range(cfg.n_layers):
+            if i < cfg.first_dense_layers:
+                ffn_f, ffn_w, kind = 2 * S * 3 * d * cfg.d_ff, \
+                    3 * d * cfg.d_ff, "llm"
+            else:
+                ffn_f, ffn_w, kind = _held_moe_cost(cfg, S)
+            g.append(_block_cost(cfg, w, f"llm.{i}", kind, attn_f + ffn_f,
+                                 _attn_weight_count(cfg) + ffn_w))
+    elif cfg.family in ("dense", "vlm", "vla", "audio"):
         attn_f = _attn_flops(cfg, S, T)
         mlp_f = 2 * S * 3 * d * cfg.d_ff
         wcount = _attn_weight_count(cfg) + 3 * d * cfg.d_ff

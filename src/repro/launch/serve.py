@@ -80,7 +80,10 @@ def executor_index(cfg: ModelConfig, graph: List[LayerCost],
 
 def build_executor(cfg: ModelConfig, ctl: RoboECC, codec: str = "int8"):
     """The split executor for ``cfg``'s family, its pool mapped from the
-    controller's Alg. 1 pool."""
+    controller's Alg. 1 pool.  Served on a TPU, a dropless MoE layer's
+    grouped matmuls run the Pallas kernel."""
+    if cfg.moe_dropless and jax.default_backend() == "tpu":
+        cfg = cfg.replace(moe_impl="pallas")
     lo = executor_index(cfg, ctl.graph, ctl.pool.start)
     hi = executor_index(cfg, ctl.graph, ctl.pool.end)
     plan = SplitPlan(lo, hi, codec=codec)

@@ -365,7 +365,10 @@ def _mla_q(cfg, p, x, positions):
     B, S, _ = x.shape
     H = cfg.n_heads
     qn, qr = cfg.qk_nope_dim, cfg.qk_rope_dim
-    q = dense(x, p["wq"]).reshape(B, S, H, qn + qr)
+    # flat projection output, as in ``_qkv``: the head split would
+    # otherwise relayout (copy) the weight in a layer loop
+    q = jax.lax.optimization_barrier(dense(x, p["wq"]))
+    q = q.reshape(B, S, H, qn + qr)
     q_nope, q_pe = q[..., :qn], q[..., qn:]
     q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
     return q_nope, q_pe
@@ -387,8 +390,10 @@ def mla_forward(cfg, p, x, positions, *, causal=True, return_kv=False):
     qn, vd, r = cfg.qk_nope_dim, cfg.v_head_dim, cfg.kv_lora_rank
     q_nope, q_pe = _mla_q(cfg, p, x, positions)
     c_kv, k_pe = _mla_latent(cfg, p, x, positions)
-    k_nope = dense(c_kv, p["wk_b"]).reshape(B, S, H, qn)
-    v = dense(c_kv, p["wv_b"]).reshape(B, S, H, vd)
+    k_nope, v = jax.lax.optimization_barrier((dense(c_kv, p["wk_b"]),
+                                              dense(c_kv, p["wv_b"])))
+    k_nope = k_nope.reshape(B, S, H, qn)
+    v = v.reshape(B, S, H, vd)
     q = jnp.concatenate([q_nope, q_pe], -1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe[:, :, None, :],
                                                   (B, S, H, cfg.qk_rope_dim))], -1)

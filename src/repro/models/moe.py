@@ -19,6 +19,17 @@ returned alongside the output.
 
 When no mesh is installed (CPU tests) the same local routine runs with all
 experts on one shard, so numerics are identical modulo capacity.
+
+A ``moe_dropless`` layer is told which experts it holds, ``[expert_start,
+expert_start + experts_held)``, as one chip of an expert-parallel
+deployment holds them.  It routes over all ``n_experts``, computes only
+its held experts' part of the output (plus the shared experts, which
+every chip computes alike) and drops nothing: the (token, choice) pairs
+routed to held experts are laid out expert by expert and run through
+grouped matmuls (``kernels/grouped_matmul``) at the rows actually routed.
+On one chip it runs without the exchange that would sum the chips'
+parts.  Its routing is by config: softmax, or DeepSeek-V3's sigmoid
+scores with bias-corrected selection (``moe_scoring="sigmoid"``).
 """
 from __future__ import annotations
 
@@ -31,8 +42,24 @@ from jax.sharding import PartitionSpec as P
 
 from ..compat import shard_map
 
-from .layers import linear_spec
+from ..kernels.grouped_matmul import ops as gmm
+from .layers import linear_spec, mlp
 from .sharding import current_mesh, shard, spec
+
+# the scopes of a dropless layer's routing and of its routed experts'
+# dispatch, grouped matmuls and combine (read back from the compiled
+# program's op_name metadata)
+ROUTER, EXPERTS = "router", "experts"
+# rows of a grouped-matmul tile: each held expert's rows start on one
+ROWS_PER_TILE = 32
+# a dropless layer's counts: (token, choice) pairs routed to held
+# experts, the most on one held expert, pairs left out of every group, and
+# held experts given at least one row (the only ones whose weights the
+# grouped matmuls read)
+COUNTS = ("routed", "max_load", "dropped", "reached")
+# the routed experts' weights, which a layer loop may hand over as whole
+# stacks for the grouped matmuls to read in place
+EXPERT_WEIGHTS = ("wg", "wu", "wd")
 
 
 def _pad_experts(n_experts: int, shards: int) -> int:
@@ -51,7 +78,8 @@ def padded_expert_count(E: int, max_shards: int = 16) -> int:
 
 def moe_specs(cfg, layers: Optional[int] = None) -> Dict:
     d, fe = cfg.d_model, cfg.moe_d_ff
-    E = padded_expert_count(cfg.n_experts)
+    E = cfg.n_experts_held if cfg.moe_dropless \
+        else padded_expert_count(cfg.n_experts)
     L = () if layers is None else (layers,)
     lax = () if layers is None else ("layers",)
     out = {
@@ -61,6 +89,9 @@ def moe_specs(cfg, layers: Optional[int] = None) -> Dict:
         "wu": spec(L + (E, d, fe), lax + ("experts", "d_model", "moe_ff")),
         "wd": spec(L + (E, fe, d), lax + ("experts", "moe_ff", "d_model")),
     }
+    if cfg.moe_scoring == "sigmoid":
+        out["e_score_correction_bias"] = spec(
+            L + (cfg.n_experts,), lax + (None,), init="zeros")
     if cfg.n_shared_experts:
         fs = fe * cfg.n_shared_experts
         out["shared"] = {
@@ -123,8 +154,101 @@ def _local_expert_ffn(x2d, gates, eids, wg, wu, wd, *, E, e0, C):
     return y[:T]
 
 
-def moe_ffn(cfg, p: Dict, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (out (B,S,d), aux_loss scalar)."""
+def _route_sigmoid(x2d, router, bias, top_k: int, scale: float):
+    """DeepSeek-V3 routing: scores ``s = sigmoid(x W_r)`` in float32, the
+    top ``k`` of ``s + bias`` selected (the bias is used for selection
+    only), gates ``scale * s_i / sum_top s_j``.  Returns (gates (T,k) f32,
+    eids (T,k) i32)."""
+    s = jax.nn.sigmoid(jnp.einsum("td,de->te", x2d, router,
+                                  preferred_element_type=jnp.float32))
+    _, eids = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    sel = jnp.take_along_axis(s, eids, axis=-1)
+    return scale * sel / (jnp.sum(sel, -1, keepdims=True) + 1e-20), eids
+
+
+def _dispatch(eids: jax.Array, e0: int, E: int, tm: int, rows: int):
+    """Lay the (token, choice) pairs routed to experts ``[e0, e0 + E)``
+    out expert by expert, each expert's rows starting on a tile of ``tm``.
+
+    Returns ``(held (T*k,) bool, pos (T*k,) the row of each held pair,
+    src (rows,) the token each row reads, sizes (E,) rows per expert
+    rounded up to whole tiles, counts int32[4]: see ``COUNTS``)``.  Rows
+    that no pair fills read token 0 and are never read back."""
+    T, k = eids.shape
+    local = eids.reshape(-1) - e0
+    held = (local >= 0) & (local < E)
+    key = jnp.where(held, local, E)
+    onehot = jax.nn.one_hot(key, E + 1, dtype=jnp.int32)[:, :E]  # (T*k, E)
+    load = jnp.sum(onehot, axis=0)                                # (E,)
+    sizes = (load + tm - 1) // tm * tm
+    start = jnp.cumsum(sizes) - sizes
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
+    pos = jnp.where(held, start[jnp.minimum(key, E - 1)] + rank, rows)
+    src = jnp.zeros((rows,), jnp.int32).at[pos].set(
+        jnp.arange(T * k, dtype=jnp.int32) // k, mode="drop")
+    routed = jnp.sum(load)
+    inside = jnp.sum(held & (pos < jnp.sum(sizes)))
+    counts = jnp.stack([routed, jnp.max(load), routed - inside,
+                        jnp.sum(load > 0)])
+    return held, pos, src, sizes, counts.astype(jnp.int32)
+
+
+def dropless_moe_ffn(cfg, p: Dict, x: jax.Array,
+                     layer: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of a MoE layer plus its shared experts, no
+    token dropped.  x: (B, S, d) -> (out (B, S, d), counts int32[4]: see
+    ``COUNTS``).  With ``layer``, the ``EXPERT_WEIGHTS`` of ``p`` are the
+    whole stacks (L, E, ., .), read at that layer in place."""
+    B, S, d = x.shape
+    k, e0, E = cfg.moe_top_k, cfg.expert_start, p["wg"].shape[-3]
+    x2d = x.reshape(B * S, d)
+    with jax.named_scope(ROUTER):
+        if cfg.moe_scoring == "sigmoid":
+            gates, eids = _route_sigmoid(x2d, p["router"],
+                                         p["e_score_correction_bias"], k,
+                                         cfg.moe_routed_scale)
+        else:
+            gates, eids, _ = _route(x2d, p["router"], k)
+    tm = ROWS_PER_TILE
+    # every pair could route to a held expert, and each expert's rows
+    # round up to whole tiles
+    rows = -(-(B * S * k + E * (tm - 1)) // tm) * tm
+    with jax.named_scope(EXPERTS):
+        held, pos, src, sizes, counts = _dispatch(eids, e0, E, tm, rows)
+        xs = x2d[src]
+        kw = {"tm": tm, "impl": cfg.moe_impl}
+        h = jax.nn.silu(gmm.grouped_matmul(xs, p["wg"], sizes, layer, **kw)
+                        ) * gmm.grouped_matmul(xs, p["wu"], sizes, layer, **kw)
+        ys = gmm.grouped_matmul(h, p["wd"], sizes, layer, **kw)
+        yp = jnp.where(held[:, None], ys[jnp.minimum(pos, rows - 1)], 0)
+        w = jnp.where(held, gates.reshape(-1), 0.0).reshape(B * S, k)
+        y = jnp.einsum("tkd,tk->td", yp.reshape(B * S, k, d), w,
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    y = y.reshape(B, S, d)
+    if cfg.n_shared_experts:
+        y = y + mlp(p["shared"], x)
+    return y, counts
+
+
+def no_counts() -> jax.Array:
+    return jnp.zeros((len(COUNTS),), jnp.int32)
+
+
+def add_counts(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Two spans' counts as one: pairs, drops and experts reached add,
+    loads take the larger."""
+    return jnp.stack([a[0] + b[0], jnp.maximum(a[1], b[1]), a[2] + b[2],
+                      a[3] + b[3]])
+
+
+def moe_ffn(cfg, p: Dict, x: jax.Array, layer: Optional[jax.Array] = None
+            ) -> Tuple[jax.Array, jax.Array]:
+    """x: (B, S, d) -> (out (B,S,d), aux): the load-balancing loss, or a
+    dropless layer's counts (``dropless_moe_ffn``, which takes
+    ``layer``)."""
+    if cfg.moe_dropless:
+        return dropless_moe_ffn(cfg, p, x, layer)
     B, S, d = x.shape
     E, k = cfg.n_experts, cfg.moe_top_k
     mesh = current_mesh()
@@ -174,6 +298,5 @@ def moe_ffn(cfg, p: Dict, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
     y = y.reshape(B, S, d)
     if cfg.n_shared_experts:
-        from .layers import mlp
         y = y + mlp(p["shared"], x)
     return shard(y, "batch", "seq", None), aux

@@ -52,16 +52,16 @@ def moe_block_specs(cfg, layers: Optional[int] = None):
     }
 
 
+def trunk_specs(cfg) -> Dict:
+    """The stacked blocks of each layer group (``_groups``)."""
+    return {name: (moe_block_specs if is_moe else dense_block_specs)(cfg, n)
+            for name, n, is_moe in _groups(cfg)}
+
+
 def lm_specs(cfg) -> Dict:
     V, d = cfg.vocab_size, cfg.d_model
     specs: Dict = {"embed": embed_spec(V, d), "final_norm": rmsnorm_spec(d)}
-    if cfg.family == "moe":
-        nd = cfg.first_dense_layers
-        if nd:
-            specs["dense_blocks"] = dense_block_specs(cfg, nd)
-        specs["moe_blocks"] = moe_block_specs(cfg, cfg.n_layers - nd)
-    else:
-        specs["blocks"] = dense_block_specs(cfg, cfg.n_layers)
+    specs.update(trunk_specs(cfg))
     if not cfg.tie_embeddings:
         specs["head"] = embed_spec(V, d)
     return specs
@@ -77,8 +77,9 @@ def _self_attn(cfg, p, x, positions, *, return_kv=False):
 
 
 def block_forward(cfg, p: Dict, x: jax.Array, positions: jax.Array,
-                  *, is_moe: bool, return_kv: bool = False):
-    """Returns (x, kv_cache_or_None, aux_loss)."""
+                  *, is_moe: bool, return_kv: bool = False, layer=None):
+    """Returns (x, kv_cache_or_None, aux) — aux as ``moe.moe_ffn`` gives
+    it, 0 for a dense block.  ``layer``: see ``moe.dropless_moe_ffn``."""
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     if cfg.parallel_block and not is_moe:
         # command-r: shared-norm parallel residual
@@ -91,7 +92,7 @@ def block_forward(cfg, p: Dict, x: jax.Array, positions: jax.Array,
     x = x + a
     h = rmsnorm(x, p["ln2"], cfg.norm_eps)
     if is_moe:
-        m, aux = moe_ffn(cfg, p["moe"], h)
+        m, aux = moe_ffn(cfg, p["moe"], h, layer)
     else:
         m, aux = mlp(p["mlp"], h), jnp.float32(0)
     return shard(x + m, "batch", "seq", None), kv, aux
@@ -161,8 +162,9 @@ def run_stack_decode(cfg, blocks_p: Tree, caches: Tree, x: jax.Array,
 
 # ================================================================ LM api
 def _groups(cfg):
-    """[(name, n_layers, is_moe)] in execution order."""
-    if cfg.family == "moe":
+    """[(name, n_layers, is_moe)] in execution order: a config with
+    experts runs its leading dense layers, then its MoE layers."""
+    if cfg.n_experts:
         g = []
         if cfg.first_dense_layers:
             g.append(("dense_blocks", cfg.first_dense_layers, False))

@@ -20,52 +20,161 @@ from . import attention as A
 from .layers import dense, embed, embed_spec, linear_spec, mlp, mlp_specs, \
     rmsnorm, rmsnorm_spec, softmax_xent, unembed
 from .sharding import spec
-from .transformer import block_forward, dense_block_specs, run_stack, \
-    run_stack_decode, lm_cache_specs, _layer_slice
+from .transformer import _groups, block_forward, run_stack, trunk_specs
 
 
 # ------------------------------------------------------------------ ViT
+# MoonViT's rotary base for the patch grid's rows and columns
+VIT_ROPE_THETA = 10_000.0
+VIT_LN_EPS = 1e-5
+
+
 def _vit_cfg(cfg):
+    """The tower's block config: heads, MLP width from the config (the
+    stand-in's 64-wide heads and 4x MLP by default)."""
     dv = cfg.vit_dim
-    hd = min(64, dv)
-    return cfg.replace(d_model=dv, n_heads=dv // hd, n_kv_heads=dv // hd,
-                       head_dim=hd, d_ff=4 * dv, causal=False,
-                       use_mla=False, parallel_block=False, qkv_bias=False)
+    heads = cfg.vit_heads or dv // min(64, dv)
+    return cfg.replace(d_model=dv, n_heads=heads, n_kv_heads=heads,
+                       head_dim=dv // heads, d_ff=cfg.vit_ff or 4 * dv,
+                       causal=False, use_mla=False, parallel_block=False,
+                       qkv_bias=cfg.vit_block == "siglip")
+
+
+def vit_tokens(cfg) -> int:
+    """Image tokens the tower hands the trunk."""
+    return cfg.n_patches // cfg.vit_merge ** 2
+
+
+def _bias(n, layers=None):
+    shape = (n,) if layers is None else (layers, n)
+    return spec(shape, (None,) * len(shape), init="zeros")
 
 
 def vit_specs(cfg) -> Dict:
-    dv = cfg.vit_dim
-    vit_cfg = _vit_cfg(cfg)
+    dv, Lv = cfg.vit_dim, cfg.vit_layers
+    vc = _vit_cfg(cfg)
+    if cfg.vit_block != "siglip":
+        return {
+            "pos_embed": spec((cfg.n_patches, dv), (None, None), scale=0.02),
+            "blocks": {
+                "ln1": rmsnorm_spec(dv, Lv),
+                "attn": A.attn_specs(vc, Lv),
+                "ln2": rmsnorm_spec(dv, Lv),
+                "mlp": mlp_specs(dv, vc.d_ff, Lv),
+            },
+            "norm": rmsnorm_spec(dv),
+            "proj": linear_spec(dv, cfg.d_model, ("d_model", None)),
+        }
+    ff, dm = vc.d_ff, cfg.vit_merge ** 2 * dv
+    attn = dict(A.attn_specs(vc, Lv), bo=_bias(dv, Lv))
     return {
         "pos_embed": spec((cfg.n_patches, dv), (None, None), scale=0.02),
         "blocks": {
-            "ln1": rmsnorm_spec(dv, cfg.vit_layers),
-            "attn": A.attn_specs(vit_cfg, cfg.vit_layers),
-            "ln2": rmsnorm_spec(dv, cfg.vit_layers),
-            "mlp": mlp_specs(dv, 4 * dv, cfg.vit_layers),
+            "ln1": rmsnorm_spec(dv, Lv), "ln1_b": _bias(dv, Lv),
+            "attn": attn,
+            "ln2": rmsnorm_spec(dv, Lv), "ln2_b": _bias(dv, Lv),
+            "mlp": {"w1": linear_spec(dv, ff, (None, "ff"), Lv),
+                    "b1": _bias(ff, Lv),
+                    "w2": linear_spec(ff, dv, ("ff", None), Lv),
+                    "b2": _bias(dv, Lv)},
         },
-        "norm": rmsnorm_spec(dv),
-        "proj": linear_spec(dv, cfg.d_model, ("d_model", None)),
+        "norm": rmsnorm_spec(dv), "norm_b": _bias(dv),
+        "proj": {"ln": rmsnorm_spec(dv), "ln_b": _bias(dv),
+                 "w1": linear_spec(dm, dm, (None, None)), "b1": _bias(dm),
+                 "w2": linear_spec(dm, cfg.d_model, (None, "d_model")),
+                 "b2": _bias(cfg.d_model)},
     }
 
 
+def layernorm(x, w, b, eps=VIT_LN_EPS):
+    """LayerNorm with gain and bias; statistics in float32."""
+    xf = x.astype(jnp.float32)
+    mu = xf.mean(-1, keepdims=True)
+    var = jnp.square(xf - mu).mean(-1, keepdims=True)
+    y = (xf - mu) * jax.lax.rsqrt(var + eps)
+    return (y * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope_2d(x: jax.Array, rows: jax.Array, cols: jax.Array,
+            theta: float = VIT_ROPE_THETA) -> jax.Array:
+    """MoonViT's 2D rotary embedding.  x: (B, P, H, D), D a multiple of 4;
+    rows, cols: (P,) grid positions.  The head splits into D/2 pairs
+    (2i, 2i+1); pair i turns by ``pos * theta ** (-4 * (i // 2) / D)``
+    with ``pos`` the row for even i and the column for odd i.  As in
+    ``layers.apply_rope``, the pair swap is a dot with a fixed signed
+    permutation, exact at HIGHEST precision."""
+    dt, D = x.dtype, x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, D, 4, dtype=jnp.float32) / D)  # D/4
+    pos = jnp.stack([rows, cols], -1).astype(jnp.float32)          # (P, 2)
+    ang = (pos[:, None, :] * freqs[None, :, None]).reshape(-1, D // 2)
+    ang = jnp.repeat(ang, 2, axis=-1)[None, :, None, :]            # (1,P,1,D)
+    rot = jnp.kron(jnp.eye(D // 2, dtype=dt),
+                   jnp.array([[0, 1], [-1, 0]], dt))   # (x0, x1) -> (-x1, x0)
+    x_rot = jnp.einsum("...d,de->...e", x, rot,
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+    out = x.astype(jnp.float32) * jnp.cos(ang) + x_rot * jnp.sin(ang)
+    return out.astype(dt)
+
+
+def _siglip_block(vc, pl, h, rows, cols):
+    """Pre-LayerNorm block: biased attention with 2D rope, tanh-GELU
+    MLP."""
+    B, P, _ = h.shape
+    a = pl["attn"]
+    q, k, v = A._qkv(vc, a, layernorm(h, pl["ln1"], pl["ln1_b"]))
+    o = A._sdpa(rope_2d(q, rows, cols),
+                rope_2d(k, rows, cols).transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3), causal=False)
+    h = h + dense(o.reshape(B, P, -1), a["wo"]) + a["bo"].astype(h.dtype)
+    m = pl["mlp"]
+    z = layernorm(h, pl["ln2"], pl["ln2_b"])
+    z = jax.nn.gelu(dense(z, m["w1"]) + m["b1"].astype(h.dtype),
+                    approximate=True)
+    return h + dense(z, m["w2"]) + m["b2"].astype(h.dtype)
+
+
+def _merge_project(cfg, p, x):
+    """k x k pixel shuffle of the patch grid, then the projector:
+    LayerNorm per patch, Linear, GELU, Linear to d_model."""
+    B, P, dv = x.shape
+    k, side = cfg.vit_merge, int(round(P ** 0.5))
+    x = layernorm(x, p["ln"], p["ln_b"])
+    x = x.reshape(B, side // k, k, side // k, k, dv).transpose(0, 1, 3, 2, 4, 5)
+    x = x.reshape(B, P // k ** 2, k * k * dv)
+    x = jax.nn.gelu(dense(x, p["w1"]) + p["b1"].astype(x.dtype),
+                    approximate=False)
+    return dense(x, p["w2"]) + p["b2"].astype(x.dtype)
+
+
 def vit_encode(cfg, p, patches: jax.Array) -> jax.Array:
-    """patches: (B, n_patches, vit_dim) -> (B, n_patches, d_model)."""
+    """patches: (B, n_patches, vit_dim) -> (B, vit_tokens, d_model)."""
     vit_cfg = _vit_cfg(cfg)
     x = patches.astype(jnp.dtype(cfg.dtype)) + p["pos_embed"].astype(
         jnp.dtype(cfg.dtype))
     positions = jnp.arange(x.shape[1])
 
-    def one(pl, h):
-        a = A.attn_forward(vit_cfg, pl["attn"],
-                           rmsnorm(h, pl["ln1"], cfg.norm_eps), positions,
-                           causal=False)
-        h = h + a
-        h = h + mlp(pl["mlp"], rmsnorm(h, pl["ln2"], cfg.norm_eps))
-        return h, None, jnp.float32(0)
+    if cfg.vit_block == "siglip":
+        side = int(round(x.shape[1] ** 0.5))
+        rows, cols = positions // side, positions % side
+
+        def one(pl, h):
+            return _siglip_block(vit_cfg, pl, h, rows, cols), None, \
+                jnp.float32(0)
+    else:
+        def one(pl, h):
+            a = A.attn_forward(vit_cfg, pl["attn"],
+                               rmsnorm(h, pl["ln1"], cfg.norm_eps), positions,
+                               causal=False)
+            h = h + a
+            h = h + mlp(pl["mlp"], rmsnorm(h, pl["ln2"], cfg.norm_eps))
+            return h, None, jnp.float32(0)
 
     x, _, _ = run_stack(vit_cfg, p["blocks"], x, one, cfg.vit_layers,
                         remat=False)
+    if cfg.vit_block == "siglip":
+        return _merge_project(cfg, p["proj"],
+                              layernorm(x, p["norm"], p["norm_b"]))
     x = rmsnorm(x, p["norm"], cfg.norm_eps)
     return dense(x, p["proj"])
 
@@ -192,15 +301,16 @@ def dit_sample(cfg, p, cognition: jax.Array, key: jax.Array) -> jax.Array:
 
 # ------------------------------------------------------------------ VLA model
 def vla_specs(cfg) -> Dict:
-    s = {
+    """Tower, embedding, the trunk's layer groups (``transformer._groups``:
+    ``blocks``, or ``dense_blocks`` and ``moe_blocks``), head."""
+    return {
         "vit": vit_specs(cfg),
         "embed": embed_spec(cfg.vocab_size, cfg.d_model),
-        "blocks": dense_block_specs(cfg, cfg.n_layers),
+        **trunk_specs(cfg),
         "final_norm": rmsnorm_spec(cfg.d_model),
         "head": embed_spec(cfg.vocab_size, cfg.d_model),
         "action": action_head_specs(cfg),
     }
-    return s
 
 
 def vla_backbone(cfg, params, patches, tokens, *, remat=False):
@@ -209,13 +319,12 @@ def vla_backbone(cfg, params, patches, tokens, *, remat=False):
     txt = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
     x = jnp.concatenate([img, txt], axis=1)
     positions = jnp.arange(x.shape[1])
+    for name, n, is_moe in _groups(cfg):
+        def one(pl, h, _moe=is_moe):
+            h, _, _ = block_forward(cfg, pl, h, positions, is_moe=_moe)
+            return h, None, jnp.float32(0)
 
-    def one(pl, h):
-        h, _, a = block_forward(cfg, pl, h, positions, is_moe=False)
-        return h, None, a
-
-    x, _, _ = run_stack(cfg, params["blocks"], x, one, cfg.n_layers,
-                        remat=remat)
+        x, _, _ = run_stack(cfg, params[name], x, one, n, remat=remat)
     return rmsnorm(x, params["final_norm"], cfg.norm_eps)
 
 

@@ -72,6 +72,7 @@ from jax.profiler import TraceAnnotation
 
 from ..core.pipeline import chunk_sizes
 from ..kernels.activation_codec import ops as codec
+from ..models import moe as MOE
 from ..models import transformer as T
 from ..models import vla as V
 from ..models.layers import embed, rmsnorm
@@ -82,9 +83,12 @@ Tree = Any
 # ``jax.named_scope`` names around each tier's work, read back from the
 # compiled programs' ``op_name`` metadata to split a tier's device time:
 # the ViT, text embed and concat (VLA edge); the block loop; the cut's
-# encode and decode; the final norm and action or LM head.
-SCOPES = ("vision", "trunk", "encode", "decode", "head")
-VISION, TRUNK, ENCODE, DECODE, HEAD = SCOPES
+# encode and decode; the final norm and action or LM head; and, inside
+# the block loop of a dropless MoE trunk, the routing and the routed
+# experts' dispatch, grouped matmuls and combine (``models/moe.py``).
+SCOPES = ("vision", "trunk", "encode", "decode", "head", MOE.ROUTER,
+          MOE.EXPERTS)
+VISION, TRUNK, ENCODE, DECODE, HEAD = SCOPES[:5]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,22 +146,59 @@ class SplitPlan:
 
 # ------------------------------------------------------------------ helpers
 def _run_blocks(cfg, blocks: Tree, x: jax.Array, positions, lo, hi, *,
-                is_moe: bool):
+                is_moe: bool, counts: Optional[jax.Array] = None):
     """Run blocks ``[lo, hi)`` of the stacked ``blocks`` tree.
 
     One loop step per block, each indexing its layer's weights out of the
     stack; the projection dots read that layer in place, so no step
     copies a weight (``models/attention._qkv``).  Either bound
     may be traced — a dynamic cut is a loop bound, so moving it inside its
-    pool recompiles nothing and no block runs on both tiers."""
-    def body(i, h):
+    pool recompiles nothing and no block runs on both tiers.  Given
+    ``counts`` (dropless MoE blocks), the loop also carries the layers'
+    dispatch counts (``models/moe.COUNTS``) and returns ``(x, counts)``.
+    A dropless MoE block's grouped matmuls get the routed experts' whole
+    stacks and read them at the step's layer in place (a kernel cannot
+    have a slice fused into its operand, so it would be a copy)."""
+    stacked = is_moe and cfg.moe_dropless
+
+    def block(i, h):
         pl = jax.tree_util.tree_map(
             lambda w: jax.lax.dynamic_index_in_dim(w, i, keepdims=False),
             blocks)
-        out, _, _ = block_forward(cfg, pl, h, positions, is_moe=is_moe)
-        return out
+        if not stacked:
+            return block_forward(cfg, pl, h, positions, is_moe=is_moe)
+        pl["moe"] = dict(pl["moe"], **{k: blocks["moe"][k]
+                                       for k in MOE.EXPERT_WEIGHTS})
+        return block_forward(cfg, pl, h, positions, is_moe=True, layer=i)
 
-    return jax.lax.fori_loop(lo, hi, body, x)
+    if counts is None:
+        return jax.lax.fori_loop(lo, hi, lambda i, h: block(i, h)[0], x)
+
+    def body(i, carry):
+        h, c = carry
+        h, _, got = block(i, h)
+        return h, MOE.add_counts(c, got)
+
+    return jax.lax.fori_loop(lo, hi, body, (x, counts))
+
+
+def _trunk_span(cfg, params: Tree, x: jax.Array, positions, lo, hi):
+    """Trunk blocks ``[lo, hi)`` across the layer groups
+    (``transformer._groups``); either bound may be a traced cut.  Returns
+    ``(x, counts)``: the dropless MoE layers' dispatch counts
+    (``models/moe.COUNTS``), zero where the span has none."""
+    off, counts = 0, MOE.no_counts()
+    with jax.named_scope(TRUNK):
+        for name, n, is_moe in T._groups(cfg):
+            a, b = _clip(lo - off, 0, n), _clip(hi - off, 0, n)
+            if is_moe and cfg.moe_dropless:
+                x, counts = _run_blocks(cfg, params[name], x, positions, a,
+                                        b, is_moe=True, counts=counts)
+            else:
+                x = _run_blocks(cfg, params[name], x, positions, a, b,
+                                is_moe=is_moe)
+            off += n
+    return x, counts
 
 
 def _clip(v, lo: int, hi: int):
@@ -404,16 +445,7 @@ class LMSplitExecutor:
             self._tail = jax.jit(self._tail_fwd)
 
     def _span(self, params, x, positions, lo, hi):
-        """Blocks ``[lo, hi)`` across the dense/MoE layer groups; either
-        bound may be a traced cut."""
-        off = 0
-        with jax.named_scope(TRUNK):
-            for name, n, is_moe in T._groups(self.cfg):
-                x = _run_blocks(self.cfg, params[name], x, positions,
-                                _clip(lo - off, 0, n), _clip(hi - off, 0, n),
-                                is_moe=is_moe)
-                off += n
-        return x
+        return _trunk_span(self.cfg, params, x, positions, lo, hi)[0]
 
     def _head(self, params, x):
         """Final norm + LM head."""
@@ -526,17 +558,21 @@ class VLASplitExecutor:
         self.action_on_cloud = action_on_cloud and not plan.two_pool
         self._edge = jax.jit(self._edge_fwd)
         self._cloud = jax.jit(self._cloud_fwd)
+        # the same programs with the trunk's dispatch counts as a further
+        # output, compiled on the first call that asks for them
+        self._edge_counted = jax.jit(self._edge_counts)
+        self._cloud_counted = jax.jit(self._cloud_counts)
         if plan.two_pool:
             self._cloud_mid = jax.jit(self._cloud_mid_fwd)
             self._tail = jax.jit(self._tail_fwd)
 
-    def _span(self, params, x, positions, lo, hi):
-        """LLM blocks ``[lo, hi)`` in graph indexing; either bound may be
-        a traced cut."""
+    def _span_counts(self, params, x, positions, lo, hi):
+        """LLM blocks ``[lo, hi)`` in graph indexing (``_trunk_span``)."""
         Lv = self.cfg.vit_layers
-        with jax.named_scope(TRUNK):
-            return _run_blocks(self.cfg, params["blocks"], x, positions,
-                               lo - Lv, hi - Lv, is_moe=False)
+        return _trunk_span(self.cfg, params, x, positions, lo - Lv, hi - Lv)
+
+    def _span(self, params, x, positions, lo, hi):
+        return self._span_counts(params, x, positions, lo, hi)[0]
 
     def _tail_slice(self) -> int:
         """Static downlink sequence length.  When pool 2 is degenerate at
@@ -570,23 +606,30 @@ class VLASplitExecutor:
                                     key), None
         raise NotImplementedError(cfg.vla_action_head)
 
-    def _edge_fwd(self, params, patches, tokens, split):
+    def _edge_counts(self, params, patches, tokens, split):
         cfg = self.cfg
         with jax.named_scope(VISION):
             img = V.vit_encode(cfg, params["vit"], patches)
             txt = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
             x = jnp.concatenate([img, txt], axis=1)
         positions = jnp.arange(x.shape[1])
-        x = self._span(params, x, positions, cfg.vit_layers, split)
-        return encode_activation(x, self.plan.wire_codec)
+        x, counts = self._span_counts(params, x, positions, cfg.vit_layers,
+                                      split)
+        return encode_activation(x, self.plan.wire_codec), counts
 
-    def _cloud_fwd(self, params, payload, split, key):
+    def _edge_fwd(self, params, patches, tokens, split):
+        return self._edge_counts(params, patches, tokens, split)[0]
+
+    def _cloud_counts(self, params, payload, split, key):
         cfg = self.cfg
         x = decode_activation(payload, cfg.dtype)
         positions = jnp.arange(x.shape[1])
-        x = self._span(params, x, positions, split,
-                       cfg.vit_layers + cfg.n_layers)
-        return self._action_decode(params, x, key)
+        x, counts = self._span_counts(params, x, positions, split,
+                                      cfg.vit_layers + cfg.n_layers)
+        return (*self._action_decode(params, x, key), counts)
+
+    def _cloud_fwd(self, params, payload, split, key):
+        return self._cloud_counts(params, payload, split, key)[:2]
 
     # -- two-pool cloud trunk: LLM blocks [split, split2)
     def _cloud_mid_fwd(self, params, payload, split, split2):
@@ -610,29 +653,41 @@ class VLASplitExecutor:
 
     def run(self, params, patches, tokens, split: int,
             key: Optional[jax.Array] = None,
-            split2: Optional[int] = None, return_logits: bool = False):
+            split2: Optional[int] = None, return_logits: bool = False,
+            return_counts: bool = False):
         """One co-inference, dispatched as in ``LMSplitExecutor.run``.
         Single-pool plans return ``(action, uplink_payload)``; two-pool
         plans take the second cut ``split2`` and return ``(action, {"up":
         ..., "down": ...})`` with the action decoded on the edge tail.
         ``return_logits`` inserts the detok head's action-position logits
-        (``None`` for other heads): ``(action, logits, payload)``."""
+        (``None`` for other heads): ``(action, logits, payload)``.
+        ``return_counts`` (single-pool plans) runs the programs that also
+        give the trunk's dispatch counts on the device, appended as a
+        dict of ``models/moe.COUNTS`` over both tiers."""
+        if return_counts and self.plan.two_pool:
+            raise NotImplementedError("dispatch counts of a two-pool plan")
+        edge = self._edge_counted if return_counts else self._edge
+        cloud = self._cloud_counted if return_counts else self._cloud
         with TraceAnnotation("roboecc/serve/edge"):
             split = jnp.int32(self.plan.clamp(split))
-            payload = self._edge(params, patches, tokens, split)
+            payload = edge(params, patches, tokens, split)
+            if return_counts:
+                payload, c_edge = payload
         with TraceAnnotation("roboecc/serve/cloud"):
             key = key if key is not None else jax.random.PRNGKey(0)
             if not self.plan.two_pool:
-                action, logits = self._cloud(params, payload, split, key)
+                action, logits, *c_cloud = cloud(params, payload, split, key)
             else:
                 split2 = jnp.int32(self.plan.clamp2(
                     split2 if split2 is not None else self.plan.pool2_end))
                 down = self._cloud_mid(params, payload, split, split2)
                 action, logits = self._tail(params, down, split2, key)
                 payload = {"up": payload, "down": down}
-        if return_logits:
-            return action, logits, payload
-        return action, payload
+        out = (action, logits, payload) if return_logits else (action, payload)
+        if return_counts:
+            both = MOE.add_counts(c_edge, c_cloud[0])
+            out += (dict(zip(MOE.COUNTS, both)),)
+        return out
 
     def run_streamed(self, params, patches, tokens, split: int,
                      n_chunks: int, key: Optional[jax.Array] = None,

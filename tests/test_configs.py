@@ -16,6 +16,7 @@ EXPECTED_PARAMS_B = {
     "seamless-m4t-large-v2": (1.4, 2.9),
     "llama-3.2-vision-11b": (9.0, 13.0),
     "zamba2-1.2b": (0.9, 1.6),
+    "kimi-vl-a3b-vla": (15.5, 17.5),      # Kimi-VL-A3B: 16B-A2.8B
 }
 
 

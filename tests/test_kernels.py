@@ -189,3 +189,37 @@ def test_codec_kernel_refuses_other_block(impl):
     # the jnp path implements every block size
     q2, s2 = codec_ops.quantize(x, impl="jnp", block=64)
     assert bool(jnp.all(q2 == q))
+
+
+@pytest.mark.parametrize("sizes,stacked", [((32, 0, 16, 48), False),
+                                           ((0, 0, 0, 0), False),
+                                           ((16, 64, 0, 32), True)])
+def test_grouped_matmul_kernel_matches_ragged_dot(sizes, stacked):
+    """The Pallas grouped matmul (interpreted) gives each group's rows the
+    product with that group's weights, as XLA's ragged dot does, with
+    empty groups skipped and, for a stack of layers, the traced layer
+    read in place."""
+    from repro.kernels.grouped_matmul import ops as gmm_ops
+    G, K, N, tm, M = 4, 64, 256, 16, 160
+    kx, kw = jax.random.split(jax.random.PRNGKey(0))
+    x = jax.random.normal(kx, (M, K)).astype(jnp.bfloat16)
+    w = jax.random.normal(kw, ((3,) if stacked else ()) + (G, K, N))
+    w = w.astype(jnp.bfloat16)
+    layer = jnp.int32(1) if stacked else None
+    g = jnp.array(sizes, jnp.int32)
+    got = gmm_ops.grouped_matmul(x, w, g, layer, tm=tm, impl="interpret")
+    want = gmm_ops.grouped_matmul(x, w, g, layer, tm=tm, impl="xla")
+    rows = sum(sizes)
+    # bf16 outputs of sums of 64 unit products (~8): one rounding is 0.03
+    np.testing.assert_allclose(np.asarray(got[:rows], np.float32),
+                               np.asarray(want[:rows], np.float32),
+                               rtol=1e-2, atol=1e-2)
+    wl = w[1] if stacked else w
+    start = 0
+    for e, n in enumerate(sizes):
+        exact = np.asarray(x[start:start + n], np.float32) \
+            @ np.asarray(wl[e], np.float32)
+        np.testing.assert_allclose(np.asarray(got[start:start + n],
+                                              np.float32), exact,
+                                   rtol=2e-2, atol=5e-2)
+        start += n

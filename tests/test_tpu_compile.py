@@ -177,3 +177,121 @@ def test_layer_loops_read_stacked_weights_in_place(one_chip, case):
     readers = loop_stack_readers(text, stacks)
     assert len(readers) >= 6
     assert {kind for _, kind in readers} == {"kOutput"}, readers
+
+
+def _kimi_chip_share():
+    """kimi-vl-a3b-vla as its benchmark cell serves it: experts [0, 16) of
+    64 held, published widths and depth, the grouped matmuls in the
+    Pallas kernel as the served path runs them on a TPU."""
+    from repro.configs import get_config
+    return get_config("kimi-vl-a3b-vla").replace(experts_held=16,
+                                                 moe_impl="pallas")
+
+
+def test_grouped_matmul_compiles_at_expert_widths(one_chip):
+    """The held experts' kernel for one call's rows (273 x 6 pairs, each
+    expert's rows on whole tiles) against the 26-layer stacks, read at a
+    traced layer."""
+    from repro.kernels.grouped_matmul import kernel as gk
+    from repro.models.moe import ROWS_PER_TILE as tm
+    M = -(-(273 * 6 + 16 * (tm - 1)) // tm) * tm
+    for K, N in ((2048, 1408), (1408, 2048)):
+        _compile(lambda x, w, g, i: gk.grouped_matmul_pallas(
+            x, w, g, tm=tm, layer=i), one_chip, ((M, K), jnp.bfloat16),
+            ((26, 16, K, N), jnp.bfloat16), ((16,), jnp.int32),
+            ((), jnp.int32))
+
+
+@pytest.mark.parametrize("case", ["dense", "moe", "vit"])
+def test_moe_vla_loops_read_stacked_weights_in_place(one_chip, case):
+    """kimi-vl-a3b-vla's layer loops at batch 1: the dense layer 0 and the
+    26 MoE layers (MLA; router, shared experts and the held experts'
+    grouped-matmul kernel) and the SigLIP-shaped tower read every stacked
+    weight in place, by a dot fusion or the kernel, with no per-step
+    copy.  The dense group is one layer deep, its stacks one layer each:
+    the compiler may prefetch them into vector memory (an asynchronous
+    copy into memory space 1), which is their one read, not a copy."""
+    from repro.launch.hlo_analysis import (loop_stack_readers,
+                                           loop_weight_copies)
+    from repro.models import moe as M
+    from repro.models import vla as V
+    from repro.models.sharding import shape_tree
+    from repro.models.transformer import trunk_specs
+    from repro.runtime.partition import _run_blocks
+
+    cfg = _kimi_chip_share()
+    R = V.vit_tokens(cfg) + 17
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if case == "vit":
+        weights = shape_tree(V.vit_specs(cfg))
+        fn = lambda w, x: V.vit_encode(cfg, w, x)
+        args = (sds((1, cfg.n_patches, cfg.vit_dim)),)
+    else:
+        name = {"dense": "dense_blocks", "moe": "moe_blocks"}[case]
+        weights = shape_tree(trunk_specs(cfg)[name])
+        n = weights["ln1"].shape[0]
+        fn = lambda w, x, lo: _run_blocks(
+            cfg, w, x, jnp.arange(R), lo, n, is_moe=case == "moe",
+            counts=M.no_counts() if case == "moe" else None)
+        args = (sds((1, R, cfg.d_model)), sds((), jnp.int32))
+    weights = jax.tree_util.tree_map(lambda s: sds(s.shape, s.dtype), weights)
+    text = jax.jit(fn).lower(weights, *args).compile().as_text()
+    stacks = [w.shape for w in jax.tree_util.tree_leaves(weights)
+              if w.ndim >= 3]
+    copies = loop_weight_copies(text, stacks)
+    if case == "dense":
+        starts = {line.split(" = ")[0].strip().lstrip("%"): line
+                  for line in text.splitlines() if " copy-start(" in line}
+        copies = [c for c in copies if not (
+            c.startswith("copy-done") or "S(1)" in starts.get(c, "")
+            .split(" copy-start(")[0].split(", ")[0])]
+    assert copies == []
+    kinds = {kind for _, kind in loop_stack_readers(text, stacks)}
+    if case == "moe":
+        assert kinds == {"kOutput", "custom-call"}, kinds
+        # the kernel sits in the loop body itself, not in a branch of a
+        # platform switch
+        assert " conditional(" not in text
+    elif case == "vit":
+        assert kinds == {"kOutput"}, kinds
+
+
+def test_kimi_split_programs_fit_one_chip(one_chip):
+    """The served kimi-vl-a3b-vla edge and cloud programs at the chip's
+    share (11.2 GB of weights) compile for one v5e and fit its memory,
+    with temporaries far below one MoE layer's held experts."""
+    from repro.models import build
+    from repro.models import vla as V
+    from repro.models.sharding import shape_tree
+    from repro.runtime.partition import SplitPlan, VLASplitExecutor
+
+    cfg = _kimi_chip_share()
+    Lv, R = cfg.vit_layers, V.vit_tokens(cfg) + 17
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shape_tree(build(cfg).param_specs))
+    param_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(params))
+    assert 11.0e9 < param_bytes < 11.4e9
+    ex = VLASplitExecutor(cfg, SplitPlan(Lv, Lv + 2, codec="int8"))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    split = sds((), jnp.int32)
+    edge = ex._edge.lower(params, sds((1, cfg.n_patches, cfg.vit_dim),
+                                      jnp.bfloat16),
+                          sds((1, 17), jnp.int32), split).compile()
+    cloud = ex._cloud.lower(
+        params, {"q": sds((1, R, cfg.d_model), jnp.int8),
+                 "s": sds((1, R, cfg.d_model // 128), jnp.float32)},
+        split, sds((2,), jnp.uint32)).compile()
+    experts_bytes = 2 * 16 * 3 * cfg.d_model * cfg.moe_d_ff
+    for prog in (edge, cloud):
+        m = prog.memory_analysis()
+        assert m.temp_size_in_bytes < experts_bytes / 8
+        assert param_bytes + m.temp_size_in_bytes \
+            + m.output_size_in_bytes < 16_909_336_064

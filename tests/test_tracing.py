@@ -1,7 +1,7 @@
 """Tracing in the served path: each tier's compiled program keeps the
 ``jax.named_scope`` names of ``runtime/partition.SCOPES`` as ``op_name``
-metadata, for the detok and DiT heads and for a split LM, and the
-executors dispatch without a recorder."""
+metadata, for the detok and DiT heads, a MoE trunk's routing and experts
+and a split LM, and the executors dispatch without a recorder."""
 import inspect
 import re
 
@@ -37,12 +37,14 @@ def _compiled(arch):
 
 
 @pytest.mark.parametrize("arch,head", [("openvla-7b", "detok"),
-                                       ("cogact-7b", "dit")])
+                                       ("cogact-7b", "dit"),
+                                       ("kimi-vl-a3b-vla", "detok")])
 def test_vla_tiers_keep_every_scope(arch, head):
     cfg, (edge, cloud) = _compiled(arch)
     assert cfg.vla_action_head == head
-    assert _scopes(edge) == {"vision", "trunk", "encode"}
-    assert _scopes(cloud) == {"decode", "trunk", "head"}
+    moe = {"router", "experts"} if cfg.moe_dropless else set()
+    assert _scopes(edge) == {"vision", "trunk", "encode"} | moe
+    assert _scopes(cloud) == {"decode", "trunk", "head"} | moe
 
 
 def test_lm_tiers_keep_their_scopes():

@@ -62,11 +62,12 @@ def _normalise(bw: np.ndarray, ref: float) -> np.ndarray:
     return np.log(np.maximum(bw, 1.0) / ref)
 
 
-def _denormalise(x: jax.Array, ref: float) -> jax.Array:
-    return jnp.exp(x) * ref
-
-
-_lstm_jit = jax.jit(lstm_forward)
+@jax.jit
+def _forecast(params: Dict, x: jax.Array, ref: jax.Array) -> jax.Array:
+    """One compiled program for a forecast: the LSTM over the (1, W)
+    normalised window and the way back to bytes/s.  ``ref`` is traced, so
+    every predictor shares the program."""
+    return jnp.exp(lstm_forward(params, x)[0]) * ref
 
 
 @dataclasses.dataclass
@@ -76,10 +77,10 @@ class Predictor:
     ref_bps: float
 
     def predict(self, window_bps: np.ndarray) -> float:
-        x = jnp.asarray(_normalise(window_bps, self.ref_bps),
-                        jnp.float32)[None, :]
-        y = _lstm_jit(self.params, x)[0]
-        return float(_denormalise(y, self.ref_bps))
+        """Next-tick bandwidth: the window normalised on the host, one
+        program on the device, one fetch."""
+        x = _normalise(window_bps, self.ref_bps).astype(np.float32)[None, :]
+        return float(_forecast(self.params, x, np.float32(self.ref_bps)))
 
     def n_bytes(self) -> int:
         return sum(v.size * v.dtype.itemsize

@@ -1,4 +1,9 @@
 """Pool, adjustment, predictor, network sim, controller, elasticity."""
+import io
+import logging
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -6,9 +11,10 @@ from repro.configs import get_config
 from repro.core import (NetworkSim, PredictorConfig, RoboECC, Thresholds,
                         TraceConfig, Workload, adjust, build_graph,
                         build_pool, calibrate_thresholds, check_granularity,
-                        generate_trace, pool_transfer_profile, search,
-                        train_predictor)
+                        generate_trace, lstm_forward, pool_transfer_profile,
+                        search, train_predictor)
 from repro.core.hardware import A100, ORIN
+from repro.core.predictor import Predictor, _forecast, _normalise
 
 
 @pytest.fixture(scope="module")
@@ -92,6 +98,55 @@ def test_predictor_beats_trivial():
         errs.append(abs(p - trace[t]))
         base.append(abs(trace[t - 1] - trace[t]))
     assert np.median(errs) < 3 * np.median(base) + 1e5
+
+
+@pytest.fixture(scope="module")
+def forecast_case():
+    """A briefly trained predictor and 200 windows of a seeded trace; the
+    first is the padded window a fresh ``NetworkSim`` hands over."""
+    trace = generate_trace(1400, seed=5)
+    pred, _ = train_predictor(trace[:1000], PredictorConfig(epochs=10))
+    w = pred.cfg.window
+    net = NetworkSim(trace[1000:])
+    net.step(3)
+    windows = [net.window(w)]
+    windows += [trace[t - w:t] for t in range(1100, 1299)]
+    assert len(windows[0]) == w and windows[0][0] == windows[0][w - 4]
+    return pred, windows
+
+
+def test_forecast_is_exact(forecast_case):
+    """One compiled program gives the eager composition's forecast bit for
+    bit, and predictors with other reference rates share that program."""
+    pred, windows = forecast_case
+    ref = pred.ref_bps
+    for win in windows:
+        x = jnp.asarray(_normalise(win, ref), jnp.float32)[None, :]
+        want = float(jnp.exp(lstm_forward(pred.params, x)[0]) * ref)
+        assert pred.predict(win) == want
+    jax.clear_caches()
+    for scale in (1.0, 0.3, 7.5):
+        other = Predictor(pred.params, pred.cfg, ref * scale)
+        assert np.isfinite(other.predict(windows[0]))
+    assert _forecast._cache_size() == 1
+
+
+def test_forecast_compiles_one_program(forecast_case):
+    pred, windows = forecast_case
+    jax.clear_caches()
+    buf = io.StringIO()
+    handler = logging.StreamHandler(buf)
+    logger = logging.getLogger("jax")
+    logger.addHandler(handler)
+    try:
+        with jax.log_compiles():
+            pred.predict(windows[1])
+    finally:
+        logger.removeHandler(handler)
+    compiled = [line for line in buf.getvalue().splitlines()
+                if line.startswith("Compiling ")]
+    assert len(compiled) == 1 and compiled[0].startswith(
+        "Compiling jit(_forecast)"), compiled
 
 
 def test_granularity_check():

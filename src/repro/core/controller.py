@@ -65,8 +65,7 @@ class RoboECC:
     participates in the planned split, not just the transfer time.
     ``adjust_codecs`` additionally lets the per-tick ΔNB move pick a codec
     jointly with the split (the first list entry is the preferred /
-    lowest-error format).  ``use_codec=True`` is the backwards-compatible
-    alias for ``codec="int8"``.
+    lowest-error format).
 
     ``multicut=True`` plans over K-segment placements
     (``core/placement.py``): Alg. 1 becomes the (S1, S2, codec) multi-cut
@@ -105,7 +104,6 @@ class RoboECC:
                  pool_overhead_target: float = 0.026,
                  nominal_bw_bps: float = 10e6,
                  thresholds: Optional[Thresholds] = None,
-                 use_codec: bool = False,
                  codec: CodecLike = None,
                  adjust_codecs: Optional[List] = None,
                  graph: Optional[List[LayerCost]] = None,
@@ -120,8 +118,6 @@ class RoboECC:
         self.cfg = cfg
         self.edge_dev, self.cloud_dev = edge, cloud
         self.workload = workload
-        if codec is None and use_codec:
-            codec = "int8"
         self.codec: Optional[Codec] = get_codec(codec)
         self.adjust_codecs = resolve_codecs(adjust_codecs)
         # `graph` lets a fleet of same-arch robots share one prebuilt graph
@@ -196,10 +192,6 @@ class RoboECC:
         s2 = self.placement.tail_cut(n)
         self.pool2: Optional[Pool] = build_pool(
             self.graph, s2, self.pool_overhead_target) if s2 < n else None
-
-    @property
-    def use_codec(self) -> bool:
-        return self.codec is not None and self.codec.name != "identity"
 
     # ------------------------------------------------------------- predictor
     def fit_predictor(self, historical_bps: np.ndarray,

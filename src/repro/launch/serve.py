@@ -2,14 +2,15 @@
 
 Drives the paper pipeline on one model: structure + hardware models ->
 Alg. 1 split -> parameter-sharing pool -> (optionally) LSTM predictor and
-per-request fine-grained adjustment, with the split executor running both
-halves of every request on this host's default device.  The data plane
-executes the same configuration the control plane planned — openvla-7b
-at its published widths by default, or its CPU-sized ``reduced()``
-variant with ``--reduced``.  Each request prints its wall time on the
-host clock, taken around work that ends in ``jax.block_until_ready``,
-beside the latency the cost model predicts for the modeled edge/cloud
-devices and network.
+per-request fine-grained adjustment, with the split executor
+(``runtime/partition.SplitExecutor``, one call form for every family)
+running both halves of every request on this host's default device.
+The data plane executes the same configuration the control plane
+planned — openvla-7b at its published widths by default, or its
+CPU-sized ``reduced()`` variant with ``--reduced``.  Each request
+prints its wall time on the host clock, taken around work that ends in
+``jax.block_until_ready``, beside the latency the cost model predicts
+for the modeled edge/cloud devices and network.
 
     PYTHONPATH=src python -m repro.launch.serve --requests 8
     PYTHONPATH=src python -m repro.launch.serve --reduced --predictor-epochs 0
@@ -87,10 +88,9 @@ def build_executor(cfg: ModelConfig, ctl: RoboECC, codec: str = "int8"):
     lo = executor_index(cfg, ctl.graph, ctl.pool.start)
     hi = executor_index(cfg, ctl.graph, ctl.pool.end)
     plan = SplitPlan(lo, hi, codec=codec)
-    if cfg.family == "vla":
-        return VLASplitExecutor(cfg, plan)
-    if cfg.family in ("dense", "moe"):
-        return LMSplitExecutor(cfg, plan)
+    for cls in (LMSplitExecutor, VLASplitExecutor):
+        if cfg.family in cls.FAMILIES:
+            return cls(cfg, plan)
     raise ValueError(f"no split executor for family {cfg.family!r}")
 
 
@@ -118,9 +118,8 @@ def compile_programs(ex, params, inputs: Tuple[jax.Array, ...],
     t0 = time.perf_counter()
     ex._edge.lower(*edge_args).compile()
     t1 = time.perf_counter()
-    cloud_args = (params, jax.eval_shape(ex._edge, *edge_args), split)
-    if isinstance(ex, VLASplitExecutor):
-        cloud_args += (jax.random.PRNGKey(0),)
+    cloud_args = (params, jax.eval_shape(ex._edge, *edge_args), split,
+                  jax.random.PRNGKey(0))
     ex._cloud.lower(*cloud_args).compile()
     return {"edge": t1 - t0, "cloud": time.perf_counter() - t1}
 
@@ -136,14 +135,12 @@ class Served:
 def serve_request(ex, params, inputs: Tuple[jax.Array, ...], split: int,
                   key: Optional[jax.Array] = None) -> Served:
     """One co-inference, timed on the host clock until every output is
-    on the device; the wait is the host span ``roboecc/serve/wait``."""
+    on the device; the wait is the host span ``roboecc/serve/wait``.
+    Every family's executor takes the same call and gives ``(out,
+    logits, payload)``; an LM's head ignores ``key``."""
     t0 = time.perf_counter()
-    if isinstance(ex, VLASplitExecutor):
-        out, logits, payload = ex.run(params, *inputs, split, key,
-                                      return_logits=True)
-    else:
-        out, payload = ex.run(params, *inputs, split)
-        logits = out
+    out, logits, payload = ex.run(params, *inputs, split, key,
+                                  return_logits=True)
     with jax.profiler.TraceAnnotation("roboecc/serve/wait"):
         jax.block_until_ready((out, logits, payload))
     return Served(out, logits, payload, time.perf_counter() - t0)

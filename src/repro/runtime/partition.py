@@ -17,6 +17,12 @@ pool recompiles nothing.  A two-pool run ships two
 payloads: the uplink cut activation (``codec``) and the downlink tail
 activation (``codec2``).
 
+One executor, ``SplitExecutor``, writes the tiers' programs, both pools,
+the streamed run and the dispatch counts once for every model family; a
+family supplies only what runs before the trunk (``_front``) and after
+it (``_head``) — ``LMSplitExecutor`` and ``VLASplitExecutor``.  Every
+executor is called the same way, ``run(params, *inputs, split, key)``.
+
 Semantics: the split is fixed for the duration of one request (one VLA action
 inference).  VLA workloads re-prefill every action step (the camera image
 changes), so caches never need to migrate across the cut — this matches the
@@ -62,7 +68,8 @@ data-dependent shape logic), outside every jitted forward.
 from __future__ import annotations
 
 import dataclasses
-import warnings
+import functools
+import inspect
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -90,6 +97,9 @@ SCOPES = ("vision", "trunk", "encode", "decode", "head", MOE.ROUTER,
           MOE.EXPERTS)
 VISION, TRUNK, ENCODE, DECODE, HEAD = SCOPES[:5]
 
+# the cut's wire formats with a data plane here: raw, int8 and int4
+WIRE_CODECS = ("", "int8", "int4")
+
 
 @dataclasses.dataclass(frozen=True)
 class SplitPlan:
@@ -99,25 +109,19 @@ class SplitPlan:
     ``codec``: "" (raw), "int8" or "int4" — the wire format for the uplink
     cut activation.  ``pool2_start``/``pool2_end`` (both ``-1`` =
     disabled) place the second pool of an edge→cloud→edge plan; ``codec2``
-    is the downlink wire format.
-
-    ``use_codec`` is a DEPRECATED alias for ``codec="int8"`` kept as a
-    warning shim for one release — pass ``codec`` explicitly
-    (``core/placement.py`` plans carry codec names per cut)."""
+    is the downlink wire format."""
     pool_start: int
     pool_end: int
-    use_codec: Optional[bool] = None
     codec: str = ""
     pool2_start: int = -1
     pool2_end: int = -1
     codec2: str = ""
 
     def __post_init__(self):
-        if self.use_codec is not None:
-            warnings.warn(
-                "SplitPlan(use_codec=...) is deprecated; pass "
-                "codec='int8' (or '') instead — use_codec will be removed "
-                "next release", DeprecationWarning, stacklevel=3)
+        for c in (self.codec, self.codec2):
+            if c not in WIRE_CODECS:
+                raise ValueError(f"no data-plane codec {c!r}; have "
+                                 f"{', '.join(map(repr, WIRE_CODECS))}")
         if (self.pool2_start >= 0) != (self.pool2_end >= 0):
             raise ValueError("pool2_start and pool2_end must be set "
                              "together (or both left at -1)")
@@ -130,12 +134,6 @@ class SplitPlan:
     @property
     def two_pool(self) -> bool:
         return self.pool2_start >= 0
-
-    @property
-    def wire_codec(self) -> str:
-        if self.codec:
-            return self.codec
-        return "int8" if self.use_codec else ""
 
     def clamp(self, split: int) -> int:
         return max(self.pool_start, min(int(split), self.pool_end))
@@ -212,13 +210,18 @@ def _codec_block(D: int) -> int:
     return 128 if D % 128 == 0 else D
 
 
-def encode_activation(x: jax.Array, wire_codec):
-    """``wire_codec``: "" / False (raw), "int8" / True, or "int4".
+def encode_activation(x: jax.Array, wire_codec: str):
+    """``wire_codec``: "" (raw), "int8" or "int4".  Any other value is
+    refused rather than shipping a different format than the planner
+    priced (planner codecs like fp16/topk have no data plane here yet).
 
     int4 requires ``x.shape[-1] % 256 == 0`` (two 128-blocks pack per
     byte lane-aligned) and raises otherwise — a silent int8 fallback
     would ship ~2x the wire bytes the planner priced."""
-    if not wire_codec:
+    if wire_codec not in WIRE_CODECS:
+        raise ValueError(f"no data-plane codec {wire_codec!r}; have "
+                         f"{', '.join(map(repr, WIRE_CODECS))}")
+    if wire_codec == "":
         return {"x": x}
     if wire_codec == "int4":
         if x.shape[-1] % 256 != 0:
@@ -228,12 +231,6 @@ def encode_activation(x: jax.Array, wire_codec):
         with jax.named_scope(ENCODE):
             p, s = codec.quantize_int4(x)
         return {"q4": p, "s": s}
-    if wire_codec not in ("int8", True):
-        # refuse rather than silently ship a different format than the
-        # planner priced (planner codecs like fp16/topk have no data
-        # plane here yet)
-        raise ValueError(f"no data-plane codec {wire_codec!r}; "
-                         "have '', 'int8', 'int4'")
     with jax.named_scope(ENCODE):
         q, s = codec.quantize(x, block=_codec_block(x.shape[-1]))
     return {"q": q, "s": s}
@@ -416,163 +413,246 @@ class DeltaTransport:
         self.ledger.drop(robot_id)
 
 
-# ================================================================ LM executor
-class LMSplitExecutor:
-    """Dense/MoE decoder-only LM split at a block boundary.
+# =============================================================== executors
+class SplitExecutor:
+    """A model split at trunk-block boundaries into an edge and a cloud
+    program, with the single- or two-pool plan in ``plan``.
 
-    Layer indexing: 0..L-1 are transformer blocks; embed always on edge.
-    Single-pool plans keep final-norm + unembed cloud-side (the paper
-    segments from the last layer towards the front, keeping the output
-    head cloud-side); a two-pool plan returns the tail — pool-2 layers
-    with ``i >= split2``, the blocks after ``pool2_end`` and the LM head —
-    to the edge, shipping a second (downlink) payload.
-    """
+    Layer indexing: the trunk's blocks are ``[first, end)``, after the
+    vision tower's ``first = cfg.vit_layers`` blocks (none for an LM);
+    ``launch/serve.executor_index`` maps a graph split onto it.  The
+    dynamic pools lie inside the trunk's range.  The edge runs the
+    family's front (``_front``) and the blocks before the cut; a
+    single-pool plan runs the rest and the family's head (``_head``) on
+    the cloud.  A two-pool plan realizes the edge→cloud→edge placement:
+    the cloud runs the trunk up to the second cut and ships the tail
+    activation back, and the edge runs the tail and the head, shipping a
+    second (downlink) payload.
+
+    A family supplies ``INPUTS`` (the names of its inputs, in call
+    order), ``_front(params, *inputs) -> x``, ``_head(params, x, key) ->
+    (out, logits)`` and, where the tail reads less than the whole
+    sequence, ``_tail_slice``.  Everything else is written here once."""
+
+    FAMILIES: Tuple[str, ...] = ()
+    INPUTS: Tuple[str, ...] = ()
 
     def __init__(self, cfg, plan: SplitPlan):
-        if cfg.family not in ("dense", "moe"):
-            raise ValueError(f"LMSplitExecutor runs dense/moe LMs, not "
-                             f"family {cfg.family!r}")
-        assert 0 <= plan.pool_start <= plan.pool_end <= cfg.n_layers
-        if plan.two_pool:
-            assert plan.pool_end <= plan.pool2_start \
-                <= plan.pool2_end <= cfg.n_layers
+        if cfg.family not in self.FAMILIES:
+            raise ValueError(f"{type(self).__name__} runs "
+                             f"{'/'.join(self.FAMILIES)} models, not family "
+                             f"{cfg.family!r}")
         self.cfg = cfg
         self.plan = plan
-        self._edge = jax.jit(self._edge_fwd)
-        self._cloud = jax.jit(self._cloud_fwd)
-        if plan.two_pool:
-            self._cloud_mid = jax.jit(self._cloud_mid_fwd)
-            self._tail = jax.jit(self._tail_fwd)
-
-    def _span(self, params, x, positions, lo, hi):
-        return _trunk_span(self.cfg, params, x, positions, lo, hi)[0]
-
-    def _head(self, params, x):
-        """Final norm + LM head."""
-        with jax.named_scope(HEAD):
-            return T.lm_logits(self.cfg, params, x)
-
-    # -- edge side: embed + blocks [0, split)
-    def _edge_fwd(self, params, tokens, split):
-        cfg, plan = self.cfg, self.plan
-        positions = jnp.arange(tokens.shape[1])
-        x = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
-        x = self._span(params, x, positions, 0, split)
-        return encode_activation(x, plan.wire_codec)
-
-    # -- cloud side (single-pool): blocks [split, L) + head
-    def _cloud_fwd(self, params, payload, split):
-        cfg = self.cfg
-        x = decode_activation(payload, cfg.dtype)
-        positions = jnp.arange(x.shape[1])
-        x = self._span(params, x, positions, split, cfg.n_layers)
-        return self._head(params, x)
-
-    # -- cloud side (two-pool): blocks [split, split2)
-    def _cloud_mid_fwd(self, params, payload, split, split2):
-        cfg, plan = self.cfg, self.plan
-        x = decode_activation(payload, cfg.dtype)
-        positions = jnp.arange(x.shape[1])
-        x = self._span(params, x, positions, split, split2)
-        return encode_activation(x, plan.codec2)
-
-    # -- edge tail (two-pool): blocks [split2, L) + head
-    def _tail_fwd(self, params, payload, split2):
-        cfg = self.cfg
-        x = decode_activation(payload, cfg.dtype)
-        positions = jnp.arange(x.shape[1])
-        x = self._span(params, x, positions, split2, cfg.n_layers)
-        return self._head(params, x)
-
-    # -- public API
-    def run(self, params, tokens, split: int,
-            split2: Optional[int] = None):
-        """One co-inference, dispatched without waiting for the device.
-        Single-pool plans return ``(logits, uplink_payload)``; two-pool
-        plans take the second cut ``split2`` and return ``(logits, {"up":
-        ..., "down": ...})`` — the logits computed on the edge tail.  The
-        host spans ``roboecc/serve/edge`` and ``roboecc/serve/cloud``
-        cover each tier's dispatch on the profiler's clock."""
-        with TraceAnnotation("roboecc/serve/edge"):
-            split = jnp.int32(self.plan.clamp(split))
-            payload = self._edge(params, tokens, split)
-        with TraceAnnotation("roboecc/serve/cloud"):
-            if not self.plan.two_pool:
-                return self._cloud(params, payload, split), payload
-            split2 = jnp.int32(self.plan.clamp2(
-                split2 if split2 is not None else self.plan.pool2_end))
-            down = self._cloud_mid(params, payload, split, split2)
-            logits = self._tail(params, down, split2)
-        return logits, {"up": payload, "down": down}
-
-    def run_streamed(self, params, tokens, split: int, n_chunks: int,
-                     split2: Optional[int] = None):
-        """One co-inference with the uplink payload shipped in
-        ``n_chunks`` token-axis chunk slices (``chunk_payload``).  Returns
-        ``(logits, chunks)`` (two-pool: ``(logits, {"up": chunks,
-        "down": payload})`` — the small downlink tail never streams).
-        Bit-identical to ``run``: the jitted forwards are chunk-agnostic
-        (no retrace across chunk counts) and the codec slices exactly."""
-        split_t = jnp.int32(self.plan.clamp(split))
-        payload = self._edge(params, tokens, split_t)
-        chunks = chunk_payload(payload, n_chunks)
-        merged = merge_chunks(chunks)
-        if not self.plan.two_pool:
-            return self._cloud(params, merged, split_t), chunks
-        split2_t = jnp.int32(self.plan.clamp2(
-            split2 if split2 is not None else self.plan.pool2_end))
-        down = self._cloud_mid(params, merged, split_t, split2_t)
-        logits = self._tail(params, down, split2_t)
-        return logits, {"up": chunks, "down": down}
-
-
-# ================================================================ VLA executor
-class VLASplitExecutor:
-    """ViT + LLM (+ action head) split; pool(s) inside the LLM block range.
-
-    Layer indexing: ViT blocks [0, Lv) — always edge-side candidates; LLM
-    blocks [Lv, Lv+L); action head after.  ``core/structure.py``'s graph
-    has one more node, the ViT projection, between the two stacks;
-    ``launch/serve.executor_index`` maps a graph split onto this indexing.
-    The dynamic pools must lie inside the LLM range; the ViT boundary is a
-    static placement choice evaluated by the cost model (DESIGN.md §7).
-
-    A two-pool plan realizes the edge→cloud→edge placement: the cloud runs
-    the trunk up to the (dynamic) second cut and ships the tail activation
-    back; the final norm + action decode run on the **edge** — ActionFlow's
-    action-stage-on-edge pattern, priced by
-    ``core/segmentation.search_multicut``.
-    """
-
-    def __init__(self, cfg, plan: SplitPlan, action_on_cloud: bool = True):
-        if cfg.family != "vla":
-            raise ValueError(f"VLASplitExecutor runs VLA models, not "
-                             f"family {cfg.family!r}")
-        self.cfg = cfg
-        self.plan = plan
-        Lv = cfg.vit_layers
-        assert Lv <= plan.pool_start <= plan.pool_end <= Lv + cfg.n_layers
-        if plan.two_pool:
-            assert plan.pool_end <= plan.pool2_start \
-                <= plan.pool2_end <= Lv + cfg.n_layers
-        self.action_on_cloud = action_on_cloud and not plan.two_pool
-        self._edge = jax.jit(self._edge_fwd)
+        self.first = cfg.vit_layers
+        self.end = self.first + cfg.n_layers
+        if not self.first <= plan.pool_start <= plan.pool_end <= self.end:
+            raise ValueError(f"pool [{plan.pool_start}, {plan.pool_end}) "
+                             f"is not inside the trunk [{self.first}, "
+                             f"{self.end})")
+        if plan.two_pool and not plan.pool2_end <= self.end:
+            raise ValueError(f"second pool [{plan.pool2_start}, "
+                             f"{plan.pool2_end}) is not inside the trunk "
+                             f"[{self.first}, {self.end})")
+        self._edge = jax.jit(self._named(self._edge_fwd))
         self._cloud = jax.jit(self._cloud_fwd)
         # the same programs with the trunk's dispatch counts as a further
         # output, compiled on the first call that asks for them
-        self._edge_counted = jax.jit(self._edge_counts)
+        self._edge_counted = jax.jit(self._named(self._edge_counts))
         self._cloud_counted = jax.jit(self._cloud_counts)
         if plan.two_pool:
             self._cloud_mid = jax.jit(self._cloud_mid_fwd)
             self._tail = jax.jit(self._tail_fwd)
 
+    def _named(self, edge):
+        """``edge(params, *inputs, split)`` under a signature that names
+        the family's inputs: the compiled program's parameters take these
+        names (``args_0_``, ... from ``*args`` otherwise)."""
+        def named(*args):
+            return edge(*args)
+        functools.update_wrapper(named, edge)
+        named.__signature__ = inspect.Signature(
+            inspect.Parameter(n, inspect.Parameter.POSITIONAL_ONLY)
+            for n in ("params", *self.INPUTS, "split"))
+        return named
+
+    # -- the family's hooks
+    def _front(self, params, *inputs):
+        raise NotImplementedError
+
+    def _head(self, params, x, key):
+        raise NotImplementedError
+
+    def _tail_slice(self) -> int:
+        """Static downlink sequence length; 0 ships the whole sequence."""
+        return 0
+
+    # -- the tiers' programs
     def _span_counts(self, params, x, positions, lo, hi):
-        """LLM blocks ``[lo, hi)`` in graph indexing (``_trunk_span``)."""
-        Lv = self.cfg.vit_layers
-        return _trunk_span(self.cfg, params, x, positions, lo - Lv, hi - Lv)
+        """Trunk blocks ``[lo, hi)`` in executor indexing
+        (``_trunk_span``)."""
+        return _trunk_span(self.cfg, params, x, positions, lo - self.first,
+                           hi - self.first)
 
     def _span(self, params, x, positions, lo, hi):
         return self._span_counts(params, x, positions, lo, hi)[0]
+
+    # -- edge: the front + blocks [first, split)
+    def _edge_counts(self, params, *args):
+        *inputs, split = args
+        x = self._front(params, *inputs)
+        positions = jnp.arange(x.shape[1])
+        x, counts = self._span_counts(params, x, positions, self.first,
+                                      split)
+        return encode_activation(x, self.plan.codec), counts
+
+    def _edge_fwd(self, params, *args):
+        return self._edge_counts(params, *args)[0]
+
+    # -- cloud (single-pool): blocks [split, end) + the head
+    def _cloud_counts(self, params, payload, split, key):
+        x = decode_activation(payload, self.cfg.dtype)
+        positions = jnp.arange(x.shape[1])
+        x, counts = self._span_counts(params, x, positions, split, self.end)
+        return (*self._head(params, x, key), counts)
+
+    def _cloud_fwd(self, params, payload, split, key):
+        return self._cloud_counts(params, payload, split, key)[:2]
+
+    # -- cloud (two-pool): blocks [split, split2)
+    def _cloud_mid_fwd(self, params, payload, split, split2):
+        x = decode_activation(payload, self.cfg.dtype)
+        positions = jnp.arange(x.shape[1])
+        x = self._span(params, x, positions, split, split2)
+        k = self._tail_slice()
+        if k:
+            x = x[:, -k:]       # semantic downlink: only what the tail reads
+        return encode_activation(x, self.plan.codec2)
+
+    # -- edge tail (two-pool): blocks [split2, end) + the head
+    def _tail_fwd(self, params, payload, split2, key):
+        x = decode_activation(payload, self.cfg.dtype)
+        positions = jnp.arange(x.shape[1])
+        x = self._span(params, x, positions, split2, self.end)
+        return self._head(params, x, key)
+
+    # -- public API
+    def _args(self, args, key, named: int):
+        """``args`` as the family's inputs, ``named`` further positional
+        arguments and the key, which may come last or by name."""
+        n = len(self.INPUTS) + named
+        if len(args) == n + 1 and key is None:
+            args, key = args[:n], args[n]
+        if len(args) != n:
+            raise TypeError(f"{type(self).__name__} takes "
+                            f"{', '.join(self.INPUTS)}, then {named} more "
+                            f"and optionally the key; got {len(args)}")
+        return args[:len(self.INPUTS)], args[len(self.INPUTS):], key
+
+    def run(self, params, *args, key=None, split2: Optional[int] = None,
+            return_logits: bool = False, return_counts: bool = False):
+        """One co-inference, dispatched without waiting for the device:
+        ``run(params, *inputs, split[, key])``, the inputs named in
+        ``INPUTS`` and the key the head's sampling noise (an LM's head
+        ignores it).  Single-pool plans return ``(out, uplink_payload)``;
+        two-pool plans take the second cut ``split2`` and return ``(out,
+        {"up": ..., "down": ...})`` with ``out`` computed on the edge
+        tail.  ``out`` is the action for a VLA, the logits for an LM.
+        ``return_logits`` inserts the head's logits (a VLA's detok head:
+        at the ``action_dim`` action positions; ``None`` for heads that
+        decode no tokens): ``(out, logits, payload)``.  ``return_counts``
+        (single-pool plans) runs the programs that also give the trunk's
+        dispatch counts on the device, appended as a dict of
+        ``models/moe.COUNTS`` over both tiers.  The host spans
+        ``roboecc/serve/edge`` and ``roboecc/serve/cloud`` cover each
+        tier's dispatch on the profiler's clock."""
+        inputs, (split,), key = self._args(args, key, 1)
+        return self._run(params, inputs, split, key, split2, None,
+                         return_logits, return_counts)
+
+    def run_streamed(self, params, *args, key=None,
+                     split2: Optional[int] = None):
+        """``run`` with the uplink payload shipped in ``n_chunks``
+        token-axis chunk slices (``chunk_payload``):
+        ``run_streamed(params, *inputs, split, n_chunks[, key])``.
+        Returns ``(out, chunks)`` (two-pool: ``(out, {"up": chunks,
+        "down": payload})`` — the small downlink tail never streams).
+        Bit-identical to ``run``: the jitted forwards are chunk-agnostic
+        (no retrace across chunk counts) and the codec slices exactly."""
+        inputs, (split, n_chunks), key = self._args(args, key, 2)
+        return self._run(params, inputs, split, key, split2, n_chunks)
+
+    def _run(self, params, inputs, split, key, split2, n_chunks,
+             return_logits=False, return_counts=False):
+        plan = self.plan
+        if return_counts and plan.two_pool:
+            raise NotImplementedError("dispatch counts of a two-pool plan")
+        edge = self._edge_counted if return_counts else self._edge
+        cloud = self._cloud_counted if return_counts else self._cloud
+        with TraceAnnotation("roboecc/serve/edge"):
+            split = jnp.int32(plan.clamp(split))
+            payload = edge(params, *inputs, split)
+            if return_counts:
+                payload, c_edge = payload
+        shipped = payload
+        if n_chunks is not None:
+            shipped = chunk_payload(payload, n_chunks)
+            payload = merge_chunks(shipped)
+        with TraceAnnotation("roboecc/serve/cloud"):
+            key = key if key is not None else jax.random.PRNGKey(0)
+            if not plan.two_pool:
+                out, logits, *c_cloud = cloud(params, payload, split, key)
+            else:
+                split2 = jnp.int32(plan.clamp2(
+                    split2 if split2 is not None else plan.pool2_end))
+                down = self._cloud_mid(params, payload, split, split2)
+                out, logits = self._tail(params, down, split2, key)
+                shipped = {"up": shipped, "down": down}
+        result = (out, logits, shipped) if return_logits else (out, shipped)
+        if return_counts:
+            both = MOE.add_counts(c_edge, c_cloud[0])
+            result += (dict(zip(MOE.COUNTS, both)),)
+        return result
+
+
+class LMSplitExecutor(SplitExecutor):
+    """Dense/MoE decoder-only LM: the embedding is always on the edge,
+    and the head (final norm + unembed) follows the trunk on whichever
+    tier owns the last segment — the cloud for a single-pool plan (the
+    paper segments from the last layer towards the front, keeping the
+    output head cloud-side)."""
+
+    FAMILIES = ("dense", "moe")
+    INPUTS = ("tokens",)
+
+    def _front(self, params, tokens):
+        return embed(params["embed"], tokens).astype(jnp.dtype(self.cfg.dtype))
+
+    def _head(self, params, x, key):
+        """Final norm + LM head; the logits are the output too."""
+        with jax.named_scope(HEAD):
+            logits = T.lm_logits(self.cfg, params, x)
+        return logits, logits
+
+
+class VLASplitExecutor(SplitExecutor):
+    """ViT + LLM + action head.  The ViT blocks ``[0, Lv)`` are always
+    edge-side; ``core/structure.py``'s graph has one more node, the ViT
+    projection, between the two stacks.  The ViT boundary is a static
+    placement choice evaluated by the cost model (DESIGN.md §7).  A
+    two-pool plan decodes the action on the edge — ActionFlow's
+    action-stage-on-edge pattern, priced by
+    ``core/segmentation.search_multicut``."""
+
+    FAMILIES = ("vla",)
+    INPUTS = ("patches", "tokens")
+
+    def _front(self, params, patches, tokens):
+        cfg = self.cfg
+        with jax.named_scope(VISION):
+            img = V.vit_encode(cfg, params["vit"], patches)
+            txt = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
+            return jnp.concatenate([img, txt], axis=1)
 
     def _tail_slice(self) -> int:
         """Static downlink sequence length.  When pool 2 is degenerate at
@@ -584,17 +664,16 @@ class VLASplitExecutor:
         planner prices those mid-trunk cuts at full activation too).
         0 means "ship everything"."""
         cfg, plan = self.cfg, self.plan
-        if plan.pool2_start == plan.pool2_end == cfg.vit_layers \
-                + cfg.n_layers:
+        if plan.pool2_start == plan.pool2_end == self.end:
             return cfg.action_dim if cfg.vla_action_head in ("detok", "") \
                 else 1
         return 0
 
-    def _action_decode(self, params, x, key):
-        """Final norm + action decode (models.vla.vla_forward tail) — runs
-        on whichever tier owns the last segment.  Returns ``(action,
-        logits)``: the detok head's logits at the ``action_dim`` action
-        positions, ``None`` for heads that decode no tokens."""
+    def _head(self, params, x, key):
+        """Final norm + action decode (models.vla.vla_forward tail).
+        Returns ``(action, logits)``: the detok head's logits at the
+        ``action_dim`` action positions, ``None`` for heads that decode
+        no tokens."""
         cfg = self.cfg
         with jax.named_scope(HEAD):
             h = rmsnorm(x, params["final_norm"], cfg.norm_eps)
@@ -605,106 +684,3 @@ class VLASplitExecutor:
                 return V.dit_sample(cfg, params["action"], h[:, -1],
                                     key), None
         raise NotImplementedError(cfg.vla_action_head)
-
-    def _edge_counts(self, params, patches, tokens, split):
-        cfg = self.cfg
-        with jax.named_scope(VISION):
-            img = V.vit_encode(cfg, params["vit"], patches)
-            txt = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
-            x = jnp.concatenate([img, txt], axis=1)
-        positions = jnp.arange(x.shape[1])
-        x, counts = self._span_counts(params, x, positions, cfg.vit_layers,
-                                      split)
-        return encode_activation(x, self.plan.wire_codec), counts
-
-    def _edge_fwd(self, params, patches, tokens, split):
-        return self._edge_counts(params, patches, tokens, split)[0]
-
-    def _cloud_counts(self, params, payload, split, key):
-        cfg = self.cfg
-        x = decode_activation(payload, cfg.dtype)
-        positions = jnp.arange(x.shape[1])
-        x, counts = self._span_counts(params, x, positions, split,
-                                      cfg.vit_layers + cfg.n_layers)
-        return (*self._action_decode(params, x, key), counts)
-
-    def _cloud_fwd(self, params, payload, split, key):
-        return self._cloud_counts(params, payload, split, key)[:2]
-
-    # -- two-pool cloud trunk: LLM blocks [split, split2)
-    def _cloud_mid_fwd(self, params, payload, split, split2):
-        cfg, plan = self.cfg, self.plan
-        x = decode_activation(payload, cfg.dtype)
-        positions = jnp.arange(x.shape[1])
-        x = self._span(params, x, positions, split, split2)
-        k = self._tail_slice()
-        if k:
-            x = x[:, -k:]       # semantic downlink: only what the tail reads
-        return encode_activation(x, plan.codec2)
-
-    # -- two-pool edge tail: LLM blocks [split2, Lv+L) + action
-    def _tail_fwd(self, params, payload, split2, key):
-        cfg = self.cfg
-        x = decode_activation(payload, cfg.dtype)
-        positions = jnp.arange(x.shape[1])
-        x = self._span(params, x, positions, split2,
-                       cfg.vit_layers + cfg.n_layers)
-        return self._action_decode(params, x, key)
-
-    def run(self, params, patches, tokens, split: int,
-            key: Optional[jax.Array] = None,
-            split2: Optional[int] = None, return_logits: bool = False,
-            return_counts: bool = False):
-        """One co-inference, dispatched as in ``LMSplitExecutor.run``.
-        Single-pool plans return ``(action, uplink_payload)``; two-pool
-        plans take the second cut ``split2`` and return ``(action, {"up":
-        ..., "down": ...})`` with the action decoded on the edge tail.
-        ``return_logits`` inserts the detok head's action-position logits
-        (``None`` for other heads): ``(action, logits, payload)``.
-        ``return_counts`` (single-pool plans) runs the programs that also
-        give the trunk's dispatch counts on the device, appended as a
-        dict of ``models/moe.COUNTS`` over both tiers."""
-        if return_counts and self.plan.two_pool:
-            raise NotImplementedError("dispatch counts of a two-pool plan")
-        edge = self._edge_counted if return_counts else self._edge
-        cloud = self._cloud_counted if return_counts else self._cloud
-        with TraceAnnotation("roboecc/serve/edge"):
-            split = jnp.int32(self.plan.clamp(split))
-            payload = edge(params, patches, tokens, split)
-            if return_counts:
-                payload, c_edge = payload
-        with TraceAnnotation("roboecc/serve/cloud"):
-            key = key if key is not None else jax.random.PRNGKey(0)
-            if not self.plan.two_pool:
-                action, logits, *c_cloud = cloud(params, payload, split, key)
-            else:
-                split2 = jnp.int32(self.plan.clamp2(
-                    split2 if split2 is not None else self.plan.pool2_end))
-                down = self._cloud_mid(params, payload, split, split2)
-                action, logits = self._tail(params, down, split2, key)
-                payload = {"up": payload, "down": down}
-        out = (action, logits, payload) if return_logits else (action, payload)
-        if return_counts:
-            both = MOE.add_counts(c_edge, c_cloud[0])
-            out += (dict(zip(MOE.COUNTS, both)),)
-        return out
-
-    def run_streamed(self, params, patches, tokens, split: int,
-                     n_chunks: int, key: Optional[jax.Array] = None,
-                     split2: Optional[int] = None):
-        """One co-inference with the uplink payload shipped in
-        ``n_chunks`` token-axis chunk slices — the VLA sibling of
-        ``LMSplitExecutor.run_streamed`` (actions bit-identical to
-        ``run``; one trace per function across chunk counts)."""
-        split_t = jnp.int32(self.plan.clamp(split))
-        payload = self._edge(params, patches, tokens, split_t)
-        chunks = chunk_payload(payload, n_chunks)
-        merged = merge_chunks(chunks)
-        key = key if key is not None else jax.random.PRNGKey(0)
-        if not self.plan.two_pool:
-            return self._cloud(params, merged, split_t, key)[0], chunks
-        split2_t = jnp.int32(self.plan.clamp2(
-            split2 if split2 is not None else self.plan.pool2_end))
-        down = self._cloud_mid(params, merged, split_t, split2_t)
-        action, _ = self._tail(params, down, split2_t, key)
-        return action, {"up": chunks, "down": down}

@@ -279,14 +279,6 @@ def test_controller_codec_plans_different_split():
     assert e4 + c4_ + t4 < e + c + t_raw
 
 
-def test_controller_use_codec_alias():
-    from repro.core import RoboECC
-    cfg = get_config("openvla-7b")
-    ctl = RoboECC(cfg, ORIN, A100, use_codec=True)
-    assert ctl.codec is not None and ctl.codec.name == "int8"
-    assert ctl.use_codec
-
-
 def test_controller_adjust_resolves_custom_codec_instances():
     """tick() must resolve the adjuster's decision within adjust_codecs —
     a registry lookup would KeyError on custom Codec instances."""
@@ -320,6 +312,22 @@ def test_encode_activation_rejects_unknown_codec():
     assert "q4" in encode_activation(x, "int4")
     assert "q" in encode_activation(x, "int8")
     assert "x" in encode_activation(x, "")
+
+
+@pytest.mark.parametrize("wire", [True, False, None, "fp8"])
+def test_split_executor_refuses_a_codec_that_is_not_a_name(wire):
+    """The wire format is a codec name: a flag or a planner codec with no
+    data plane is refused by the plan, for either cut, and by the
+    encoder, not shipped as some format of its own."""
+    from repro.runtime.partition import SplitPlan, encode_activation
+    x = jnp.ones((1, 4, 256), jnp.bfloat16)
+    with pytest.raises(ValueError, match="no data-plane codec"):
+        encode_activation(x, wire)
+    with pytest.raises(ValueError, match="no data-plane codec"):
+        SplitPlan(0, 2, codec=wire)
+    with pytest.raises(ValueError, match="no data-plane codec"):
+        SplitPlan(0, 2, codec="int8", pool2_start=2, pool2_end=3,
+                  codec2=wire)
 
 
 def test_fleet_codec_axis_beats_identity_on_slow_links():

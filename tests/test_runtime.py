@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch import serve
 from repro.models import build
 from repro.models.transformer import lm_hidden, lm_logits
 from repro.runtime.partition import (LMSplitExecutor, SplitPlan,
@@ -50,21 +51,6 @@ def test_lm_split_codec_halves_payload(lm_setup):
     assert rel < 0.05     # int8 cut tensor stays within a few percent
 
 
-def test_split_plan_use_codec_deprecation_shim():
-    """``use_codec`` stays a working alias for one release — but warns."""
-    with pytest.warns(DeprecationWarning, match="use_codec"):
-        plan = SplitPlan(2, 5, use_codec=True)
-    assert plan.wire_codec == "int8"
-    with pytest.warns(DeprecationWarning):
-        plan_off = SplitPlan(2, 5, use_codec=False)
-    assert plan_off.wire_codec == ""
-    # the replacement spelling warns nothing
-    import warnings as _w
-    with _w.catch_warnings():
-        _w.simplefilter("error")
-        assert SplitPlan(2, 5, codec="int8").wire_codec == "int8"
-
-
 def test_lm_two_pool_equivalence_all_cut_pairs(lm_setup):
     """Two-pool (edge→cloud→edge) forward must match the monolithic
     forward for EVERY (split, split2) inside the pools — and moving either
@@ -87,7 +73,8 @@ def test_lm_two_pool_equivalence_all_cut_pairs(lm_setup):
     ex._tail = jax.jit(count("tail", orig_tail))
     for split in range(1, 4):
         for split2 in range(4, 7):
-            logits, payloads = ex.run(params, tokens, split, split2)
+            logits, payloads = ex.run(params, tokens, split,
+                                      split2=split2)
             assert set(payloads) == {"up", "down"}
             np.testing.assert_allclose(np.asarray(logits), np.asarray(ref),
                                        rtol=2e-4, atol=2e-4)
@@ -265,6 +252,53 @@ def test_vla_split_equivalence():
     ex = VLASplitExecutor(cfg, SplitPlan(Lv + 1, Lv + 3))
     act, _ = ex.run(params, patches, tokens, Lv + 2, key)
     np.testing.assert_allclose(np.asarray(act), np.asarray(ref), atol=1e-5)
+
+
+@pytest.mark.parametrize("arch", ["llama3.2-3b", "openvla-7b", "cogact-7b",
+                                  "kimi-vl-a3b-vla"])
+def test_moving_the_split_traces_each_tier_once(arch):
+    """For a dense LM, a VLA with the detok head, one with the DiT head
+    and the MoE VLA (at ``test_moe_vla``'s CPU size): moving the split
+    across its whole pool traces each tier once, and the tiers' programs
+    keep the names ``_edge_fwd`` / ``_cloud_fwd`` that the benchmark's
+    device-trace readers match."""
+    from test_moe_vla import SMALL
+    cfg = get_config(arch)
+    cfg = cfg.replace(**SMALL) if cfg.moe_dropless else \
+        cfg.reduced().replace(n_layers=3)
+    lo, hi = cfg.vit_layers, cfg.vit_layers + cfg.n_layers
+    cls = VLASplitExecutor if cfg.family == "vla" else LMSplitExecutor
+    ex = cls(cfg, SplitPlan(lo, hi, codec="int8"))
+    params = build(cfg).init(jax.random.PRNGKey(0))
+    inputs = serve.make_inputs(cfg, jax.random.PRNGKey(1))
+    key = jax.random.PRNGKey(2)
+    traces = {"_front": 0, "_head": 0}
+
+    def count(name, fn):
+        def wrapped(*a):
+            traces[name] += 1
+            return fn(*a)
+        return wrapped
+
+    # the hooks run only while their tier's program is traced
+    ex._front = count("_front", ex._front)
+    ex._head = count("_head", ex._head)
+    for split in range(lo, hi + 1):
+        out, payload = ex.run(params, *inputs, split, key)
+        assert np.isfinite(np.asarray(out, np.float32)).all()
+    assert traces == {"_front": 1, "_head": 1}
+    edge_args = (params, *inputs, jnp.int32(lo))
+    cloud_args = (params, jax.eval_shape(ex._edge, *edge_args),
+                  jnp.int32(lo), key)
+    edge = ex._edge.lower(*edge_args)
+    assert edge.as_text().startswith("module @jit__edge_fwd ")
+    assert ex._cloud.lower(*cloud_args).as_text().startswith(
+        "module @jit__cloud_fwd ")
+    # the edge program's parameters carry the family's input names, as
+    # the per-family programs' did
+    entry = edge.compile().as_text().split("\nENTRY ")[1]
+    for name in (*ex.INPUTS, "split"):
+        assert f"%{name}." in entry, name
 
 
 def test_greedy_generate():

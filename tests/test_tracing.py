@@ -29,9 +29,8 @@ def _compiled(arch):
     inputs = serve.make_inputs(cfg, jax.random.PRNGKey(1))
     split = jnp.int32(ex.plan.pool_start)
     edge_args = (params, *inputs, split)
-    cloud_args = (params, jax.eval_shape(ex._edge, *edge_args), split)
-    if isinstance(ex, VLASplitExecutor):
-        cloud_args += (jax.random.PRNGKey(2),)
+    cloud_args = (params, jax.eval_shape(ex._edge, *edge_args), split,
+                  jax.random.PRNGKey(2))
     return cfg, (ex._edge.lower(*edge_args).compile().as_text(),
                  ex._cloud.lower(*cloud_args).compile().as_text())
 

@@ -24,9 +24,21 @@ A ``moe_dropless`` layer is told which experts it holds, ``[expert_start,
 expert_start + experts_held)``, as one chip of an expert-parallel
 deployment holds them.  It routes over all ``n_experts``, computes only
 its held experts' part of the output (plus the shared experts, which
-every chip computes alike) and drops nothing: the (token, choice) pairs
-routed to held experts are laid out expert by expert and run through
-grouped matmuls (``kernels/grouped_matmul``) at the rows actually routed.
+every chip computes alike) and drops nothing.  Which path runs its held
+experts follows from the layer's shapes and ``moe_impl``:
+
+- a token block whose rows and f32 sum fit the kernel's vector memory
+  beside two experts' weights (``kernels/grouped_matmul.routed_ffn_fits``;
+  one robot's 273 rows at kimi-vl-a3b-vla's widths) runs in one Pallas
+  kernel per layer, ``routed_ffn``: per held expert, a ``(T,)`` row of
+  each token's rank among the expert's rows (or -1) and its gate, built
+  with dense ops, and the list of held experts given rows.  The kernel
+  gathers and combines the rows itself, so no routed row reaches HBM;
+- a larger block, and ``moe_impl="xla"`` (the CPU reference), lays the
+  (token, choice) pairs routed to held experts out expert by expert and
+  runs them through grouped matmuls (``kernels/grouped_matmul``, XLA's
+  ragged dot under ``"xla"``) at the rows actually routed.
+
 On one chip it runs without the exchange that would sum the chips'
 parts.  Its routing is by config: softmax, or DeepSeek-V3's sigmoid
 scores with bias-corrected selection (``moe_scoring="sigmoid"``).
@@ -42,23 +54,24 @@ from jax.sharding import PartitionSpec as P
 
 from ..compat import shard_map
 
+from ..kernels.grouped_matmul import kernel as gmk
 from ..kernels.grouped_matmul import ops as gmm
 from .layers import linear_spec, mlp
 from .sharding import current_mesh, shard, spec
 
 # the scopes of a dropless layer's routing and of its routed experts'
-# dispatch, grouped matmuls and combine (read back from the compiled
+# dispatch, expert FFNs and combine (read back from the compiled
 # program's op_name metadata)
 ROUTER, EXPERTS = "router", "experts"
 # rows of a grouped-matmul tile: each held expert's rows start on one
 ROWS_PER_TILE = 32
 # a dropless layer's counts: (token, choice) pairs routed to held
-# experts, the most on one held expert, pairs left out of every group, and
+# experts, the most on one held expert, pairs left out of every tile, and
 # held experts given at least one row (the only ones whose weights the
-# grouped matmuls read)
+# kernels read)
 COUNTS = ("routed", "max_load", "dropped", "reached")
 # the routed experts' weights, which a layer loop may hand over as whole
-# stacks for the grouped matmuls to read in place
+# stacks for the kernels to read in place
 EXPERT_WEIGHTS = ("wg", "wu", "wd")
 
 
@@ -193,6 +206,67 @@ def _dispatch(eids: jax.Array, e0: int, E: int, tm: int, rows: int):
     return held, pos, src, sizes, counts.astype(jnp.int32)
 
 
+def _tables(gates: jax.Array, eids: jax.Array, e0: int, E: int, tm: int):
+    """The routed kernel's view of the pairs routed to experts ``[e0, e0
+    + E)``, in dense ops (``top_k`` picks distinct experts, so a token
+    is at most one row of an expert).
+
+    Returns ``(rank (E, T) f32: each token's row among the expert's rows,
+    -1 where it is not routed to it; gate (E, T) f32: its gate there;
+    tiles (E,): each expert's tiles of tm rows; source (E,): the expert
+    whose weights the kernel's step for each fetches, see
+    ``routed_ffn_pallas``; counts int32[4]: see ``COUNTS``)``."""
+    T = eids.shape[0]
+    hit = eids[:, :, None] == e0 + jnp.arange(E, dtype=eids.dtype)
+    mask = jnp.any(hit, axis=1)                                  # (T, E)
+    gate = jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1).T
+    # a token's rank is the count of the expert's tokens before it: one
+    # dot with the strict upper triangle (exact: 0/1 products, f32 sums)
+    before = jnp.arange(T)[:, None] < jnp.arange(T)[None, :]
+    rank = jnp.einsum("se,st->et", mask.astype(jnp.bfloat16),
+                      before.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+    rank = jnp.where(mask.T, rank, -1.0)
+    load = jnp.sum(mask, axis=0, dtype=jnp.int32)                # (E,)
+    tiles = (load + tm - 1) // tm
+    order = jnp.arange(E)
+    has = load > 0
+    # a step whose expert has no rows fetches the next one with rows,
+    # else the last one
+    ahead = has[None, :] & (order[None, :] >= order[:, None])    # (step, e)
+    later = jnp.min(jnp.where(ahead, order[None, :], E), axis=1)
+    source = jnp.where(later < E, later, jnp.max(jnp.where(has, order, 0)))
+    routed = jnp.sum(load)
+    covered = jnp.sum(jnp.minimum(load, tm * tiles))
+    counts = jnp.stack([routed, jnp.max(load), routed - covered,
+                        jnp.sum(has)])
+    return (rank, gate, tiles.astype(jnp.int32), source.astype(jnp.int32),
+            counts.astype(jnp.int32))
+
+
+def _grouped_experts(cfg, p: Dict, x2d: jax.Array, gates: jax.Array,
+                     eids: jax.Array, layer: Optional[jax.Array]):
+    """The held experts through grouped matmuls over the routed rows laid
+    out expert by expert: ``(y (T, d), counts)``."""
+    T, d = x2d.shape
+    k, e0, E = cfg.moe_top_k, cfg.expert_start, p["wg"].shape[-3]
+    tm = ROWS_PER_TILE
+    # every pair could route to a held expert, and each expert's rows
+    # round up to whole tiles
+    rows = -(-(T * k + E * (tm - 1)) // tm) * tm
+    held, pos, src, sizes, counts = _dispatch(eids, e0, E, tm, rows)
+    xs = x2d[src]
+    kw = {"tm": tm, "impl": cfg.moe_impl}
+    h = jax.nn.silu(gmm.grouped_matmul(xs, p["wg"], sizes, layer, **kw)
+                    ) * gmm.grouped_matmul(xs, p["wu"], sizes, layer, **kw)
+    ys = gmm.grouped_matmul(h, p["wd"], sizes, layer, **kw)
+    yp = jnp.where(held[:, None], ys[jnp.minimum(pos, rows - 1)], 0)
+    w = jnp.where(held, gates.reshape(-1), 0.0).reshape(T, k)
+    y = jnp.einsum("tkd,tk->td", yp.reshape(T, k, d), w,
+                   preferred_element_type=jnp.float32)
+    return y.astype(x2d.dtype), counts
+
+
 def dropless_moe_ffn(cfg, p: Dict, x: jax.Array,
                      layer: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, jax.Array]:
@@ -201,7 +275,7 @@ def dropless_moe_ffn(cfg, p: Dict, x: jax.Array,
     ``COUNTS``).  With ``layer``, the ``EXPERT_WEIGHTS`` of ``p`` are the
     whole stacks (L, E, ., .), read at that layer in place."""
     B, S, d = x.shape
-    k, e0, E = cfg.moe_top_k, cfg.expert_start, p["wg"].shape[-3]
+    k, E = cfg.moe_top_k, p["wg"].shape[-3]
     x2d = x.reshape(B * S, d)
     with jax.named_scope(ROUTER):
         if cfg.moe_scoring == "sigmoid":
@@ -210,21 +284,17 @@ def dropless_moe_ffn(cfg, p: Dict, x: jax.Array,
                                          cfg.moe_routed_scale)
         else:
             gates, eids, _ = _route(x2d, p["router"], k)
-    tm = ROWS_PER_TILE
-    # every pair could route to a held expert, and each expert's rows
-    # round up to whole tiles
-    rows = -(-(B * S * k + E * (tm - 1)) // tm) * tm
     with jax.named_scope(EXPERTS):
-        held, pos, src, sizes, counts = _dispatch(eids, e0, E, tm, rows)
-        xs = x2d[src]
-        kw = {"tm": tm, "impl": cfg.moe_impl}
-        h = jax.nn.silu(gmm.grouped_matmul(xs, p["wg"], sizes, layer, **kw)
-                        ) * gmm.grouped_matmul(xs, p["wu"], sizes, layer, **kw)
-        ys = gmm.grouped_matmul(h, p["wd"], sizes, layer, **kw)
-        yp = jnp.where(held[:, None], ys[jnp.minimum(pos, rows - 1)], 0)
-        w = jnp.where(held, gates.reshape(-1), 0.0).reshape(B * S, k)
-        y = jnp.einsum("tkd,tk->td", yp.reshape(B * S, k, d), w,
-                       preferred_element_type=jnp.float32).astype(x.dtype)
+        if cfg.moe_impl != "xla" and gmk.routed_ffn_fits(
+                B * S, d, p["wg"].shape[-1], x.dtype.itemsize):
+            tm = gmk.ROUTED_TILE
+            rank, gate, tiles, source, counts = _tables(
+                gates, eids, cfg.expert_start, E, tm)
+            y = gmm.routed_ffn(x2d, rank, gate, tiles, source, p["wg"],
+                               p["wu"], p["wd"], layer, tm=tm,
+                               impl=cfg.moe_impl)
+        else:
+            y, counts = _grouped_experts(cfg, p, x2d, gates, eids, layer)
     y = y.reshape(B, S, d)
     if cfg.n_shared_experts:
         y = y + mlp(p["shared"], x)

@@ -223,3 +223,55 @@ def test_grouped_matmul_kernel_matches_ragged_dot(sizes, stacked):
                                               np.float32), exact,
                                    rtol=2e-2, atol=5e-2)
         start += n
+
+
+def _held_routing(case, T, k, n_router, e0, E):
+    """(gates, eids) of ``T`` tokens choosing ``k`` distinct experts of
+    ``n_router``, experts ``[e0, e0 + E)`` held: spread (``balanced``,
+    ``stacked``), every token on held expert ``e0 + 1``
+    (``one_takes_all``), none on held ``e0`` and ``e0 + 2``
+    (``some_empty``), or none on any held expert (``none_routed``)."""
+    s = jax.random.uniform(jax.random.PRNGKey(5), (T, n_router))
+    s = {"one_takes_all": s.at[:, e0 + 1].add(10.0),
+         "some_empty": s.at[:, jnp.array([e0, e0 + 2])].add(-10.0),
+         "none_routed": s.at[:, e0:e0 + E].add(-10.0)}.get(case, s)
+    g, eids = jax.lax.top_k(s, k)
+    return g / g.sum(-1, keepdims=True), eids.astype(jnp.int32)
+
+
+@pytest.mark.parametrize("case", ["balanced", "one_takes_all", "some_empty",
+                                  "none_routed", "stacked"])
+def test_routed_ffn_kernel_matches_reference(case):
+    """The routed-FFN kernel (interpreted) gives each token the gated sum
+    of the SwiGLU of the held experts it is routed to, as the XLA
+    reference does: one expert over all the tokens in several tiles, held
+    experts given no rows (their steps fetch another's weights), nothing
+    routed (a zero sum), and a stack of layers read at a traced layer."""
+    from repro.kernels.grouped_matmul import ops as gmm_ops
+    from repro.models.moe import _tables
+    T, d, f, k, n_router, e0, E, tm = 40, 128, 64, 2, 8, 2, 4, 16
+    gates, eids = _held_routing(case, T, k, n_router, e0, E)
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    L = (3,) if case == "stacked" else ()
+    x = jax.random.normal(ks[0], (T, d)).astype(jnp.bfloat16)
+    wg, wu, wd = (
+        (jax.random.normal(kk, L + s) / np.sqrt(s[-2])).astype(jnp.bfloat16)
+        for kk, s in zip(ks[1:], ((E, d, f), (E, d, f), (E, f, d))))
+    layer = jnp.int32(1) if case == "stacked" else None
+    rank, gate, tiles, source, _ = _tables(gates, eids, e0, E, tm)
+    args = (x, rank, gate, tiles, source, wg, wu, wd, layer)
+    got = gmm_ops.routed_ffn(*args, tm=tm, impl="interpret")
+    want = gmm_ops.routed_ffn(*args, tm=tm, impl="xla")
+    # bf16 outputs of O(1): one rounding is 0.008
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=1e-2, atol=1e-2)
+    load = np.asarray(jnp.max(rank, axis=1)) + 1
+    assert list(np.asarray(tiles)) == [-(-int(n) // tm) for n in load]
+    if case == "one_takes_all":
+        assert load[1] == T and int(tiles[1]) == 3
+    elif case == "some_empty":
+        assert load[0] == load[2] == 0 and load[1] > 0 and load[3] > 0
+        assert list(np.asarray(source)) == [1, 1, 3, 3]
+    elif case == "none_routed":
+        assert not load.any() and not np.asarray(got, np.float32).any()

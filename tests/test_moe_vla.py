@@ -123,6 +123,69 @@ def test_forced_skew_drops_nothing():
     assert _rel(y, O.moe(cfg, p, x, cfg.expert_start, 4)) < 1e-5
 
 
+def _kernel_calls(jaxpr) -> int:
+    """Pallas kernel calls in a jaxpr, each call site once, nested
+    jaxprs included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += 1
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _kernel_calls(sub)
+    return n
+
+
+@pytest.mark.parametrize("case", ["balanced", "one_takes_all", "some_empty",
+                                  "none_routed", "stacked", "above_budget"])
+def test_routed_kernel_gives_the_grouped_path(case, monkeypatch):
+    """A MoE layer's held experts through the routed kernel (interpreted)
+    give what the grouped path with XLA's ragged dot gives, with the same
+    counts: routing spread over the held experts; every token on one held
+    expert (all T rows, two tiles); held experts given no rows; no pair
+    on a held expert; the expert stacks read at a traced layer.  The
+    layer runs one kernel, or, past the kernel's vector-memory budget,
+    the grouped path's three."""
+    from repro.kernels.grouped_matmul import kernel as gmk
+    cfg = _cfg(moe_top_k=2)
+    blocks = _params(cfg)["moe_blocks"]
+    i = 1 if case == "stacked" else 0
+    p = jax.tree_util.tree_map(lambda w: w[i], blocks)["moe"]
+    layer = None
+    if case == "stacked":
+        p = dict(p, **{k: blocks["moe"][k] for k in M.EXPERT_WEIGHTS})
+        layer = jnp.int32(i)
+    push = {"one_takes_all": ([5], 50.0), "some_empty": ([4, 6], -50.0),
+            "none_routed": ([4, 5, 6, 7], -50.0)}
+    if case in push:
+        ids, by = push[case]
+        p = dict(p, e_score_correction_bias=p["e_score_correction_bias"]
+                 .at[jnp.array(ids)].add(by))
+    if case == "above_budget":
+        monkeypatch.setattr(gmk, "ROUTED_VMEM", 0)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 80, cfg.d_model))
+    fused = cfg.replace(moe_impl="interpret")
+    y, counts = M.dropless_moe_ffn(fused, p, x, layer)
+    want, want_counts = M.dropless_moe_ffn(cfg, p, x, layer)
+    assert [int(c) for c in counts] == [int(c) for c in want_counts]
+    T = 2 * 80
+    routed, load, dropped, reached = (int(c) for c in counts)
+    assert dropped == 0
+    if case == "one_takes_all":
+        assert load == T and reached >= 1 and T > gmk.ROUTED_TILE
+    elif case == "some_empty":
+        assert 0 < reached <= 2
+    elif case == "none_routed":
+        assert routed == reached == 0
+    assert _rel(y, want) < 1e-5
+    jaxpr = jax.make_jaxpr(
+        lambda x: M.dropless_moe_ffn(fused, p, x, layer))(x).jaxpr
+    assert _kernel_calls(jaxpr) == (3 if case == "above_budget" else 1)
+
+
 def test_v3_gates_by_hand():
     """Sigmoid scores; the bias picks the experts but does not weigh
     them; gates normalised over the chosen and scaled by 2.446."""

@@ -9,6 +9,8 @@ at import), and the persistent compilation cache is off around the
 compiles, since entries written for a described chip cannot be read back
 without one.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -200,6 +202,77 @@ def test_grouped_matmul_compiles_at_expert_widths(one_chip):
             x, w, g, tm=tm, layer=i), one_chip, ((M, K), jnp.bfloat16),
             ((26, 16, K, N), jnp.bfloat16), ((16,), jnp.int32),
             ((), jnp.int32))
+
+
+def test_routed_ffn_compiles_at_expert_widths(one_chip):
+    """The routed kernel for one robot's 273 rows at kimi-vl-a3b-vla's
+    widths against the 26-layer stacks of 16 held experts, read at a
+    traced layer: within its vector-memory budget, which the compile
+    holds it to."""
+    from repro.kernels.grouped_matmul import kernel as gk
+    from repro.models.moe import _tables
+    T, d, f, E = 273, 2048, 1408, 16
+    assert gk.routed_ffn_fits(T, d, f, 2)
+    assert not gk.routed_ffn_fits(4 * T, d, f, 2)    # four robots' rows
+    tm = gk.ROUTED_TILE
+
+    def layer(x, gates, eids, wg, wu, wd, i):
+        rank, gate, tiles, source, _ = _tables(gates, eids, 0, E, tm)
+        return gk.routed_ffn_pallas(x, rank, gate, tiles, source, wg, wu,
+                                    wd, tm=tm, layer=i)
+
+    _compile(layer, one_chip, ((T, d), jnp.bfloat16),
+             ((T, 6), jnp.float32), ((T, 6), jnp.int32),
+             ((26, E, d, f), jnp.bfloat16), ((26, E, d, f), jnp.bfloat16),
+             ((26, E, f, d), jnp.bfloat16), ((), jnp.int32))
+
+
+def test_kimi_moe_loop_runs_one_routed_kernel(one_chip):
+    """kimi-vl-a3b-vla's MoE layer loop at batch 1, as served on a TPU:
+    from the routing to the routed sum, each layer runs one routed-kernel
+    call and at most 11 other operations under ``experts``, none of them a
+    gather or scatter, and nothing in the loop writes more rows of the
+    model's or an expert's width than the layer has tokens."""
+    import re
+    from repro.launch.hlo_analysis import while_bodies
+    from repro.models import moe as M
+    from repro.models import vla as V
+    from repro.models.sharding import shape_tree
+    from repro.models.transformer import trunk_specs
+    from repro.runtime.partition import _run_blocks
+
+    cfg = _kimi_chip_share()
+    R = V.vit_tokens(cfg) + 17
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    weights = jax.tree_util.tree_map(
+        lambda s: sds(s.shape, s.dtype),
+        shape_tree(trunk_specs(cfg)["moe_blocks"]))
+    n = weights["ln1"].shape[0]
+    fn = lambda w, x, lo: _run_blocks(cfg, w, x, jnp.arange(R), lo, n,
+                                      is_moe=True, counts=M.no_counts())
+    text = jax.jit(fn).lower(weights, sds((1, R, cfg.d_model)),
+                             sds((), jnp.int32)).compile().as_text()
+    (body,) = while_bodies(text).values()
+    plumbing = ("bitcast", "get-tuple-element", "tuple", "parameter",
+                "constant")
+    ops = [(name, op, result, re.search(r'op_name="([^"]*)"', attrs))
+           for name, result, op, _, attrs in body if op not in plumbing]
+    experts = [(name, op, m.group(1)) for name, op, _, m in ops
+               if m and f"/{M.EXPERTS}/" in m.group(1)]
+    kernels = [name for name, op, where in experts if op == "custom-call"]
+    assert len(kernels) == 1, kernels
+    assert [where for name, op, where in experts if op == "custom-call"
+            ][0].endswith("jit(routed_ffn)/pallas_call")
+    assert len(experts) <= 12, experts
+    assert not [e for e in experts if "gather" in e[2] or "scatter" in e[2]]
+    for name, _, result, _ in ops:
+        dims = [int(x) for x in re.search(r"\[([\d,]*)\]", result).group(1)
+                .split(",") if x]
+        if dims and dims[-1] in (cfg.d_model, cfg.moe_d_ff):
+            assert math.prod(dims[:-1]) <= R, (name, result)
 
 
 @pytest.mark.parametrize("case", ["dense", "moe", "vit"])

@@ -235,24 +235,30 @@ def _instructions(lines: List[str]):
                re.findall(r"%([\w.\-]+)", rest[op.end():close]), rest[close:])
 
 
-def while_bodies(hlo_text: str) -> Dict[str, list]:
-    """Each while loop's body: the instructions of its own computation,
-    not of the computations it fuses or calls."""
-    names = set(re.findall(r"while\([^)]*\), condition=%?[\w.\-]+, "
-                           r"body=%?([\w.\-]+)", hlo_text))
+def computations(hlo_text: str) -> Dict[str, list]:
+    """Each computation's instructions, by name: its own, not those of
+    the computations it fuses or calls."""
     comps: Dict[str, List[str]] = {}
     current = None
     for line in hlo_text.splitlines():
         m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
         if m:
-            current = m.group(1) if m.group(1) in names else None
+            current = m.group(1)
             comps[current] = []
         elif current is not None and line.startswith("}"):
             current = None
         elif current is not None:
             comps[current].append(line)
-    comps.pop(None, None)
     return {n: list(_instructions(ls)) for n, ls in comps.items()}
+
+
+def while_bodies(hlo_text: str) -> Dict[str, list]:
+    """Each while loop's body: the instructions of its own computation,
+    not of the computations it fuses or calls."""
+    names = set(re.findall(r"while\([^)]*\), condition=%?[\w.\-]+, "
+                           r"body=%?([\w.\-]+)", hlo_text))
+    return {n: body for n, body in computations(hlo_text).items()
+            if n in names}
 
 
 def _elements(result: str) -> Optional[int]:

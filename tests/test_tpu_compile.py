@@ -231,10 +231,13 @@ def test_kimi_moe_loop_runs_one_routed_kernel(one_chip):
     """kimi-vl-a3b-vla's MoE layer loop at batch 1, as served on a TPU:
     from the routing to the routed sum, each layer runs one routed-kernel
     call and at most 11 other operations under ``experts``, none of them a
-    gather or scatter, and nothing in the loop writes more rows of the
-    model's or an expert's width than the layer has tokens."""
+    gather or scatter; no operation outside ``experts`` fuses an
+    instruction named under it (the benchmark files such a fusion, the
+    attention's or shared experts' dots among them, under ``experts``);
+    and nothing in the loop writes more rows of the model's or an
+    expert's width than the layer has tokens."""
     import re
-    from repro.launch.hlo_analysis import while_bodies
+    from repro.launch.hlo_analysis import computations, while_bodies
     from repro.models import moe as M
     from repro.models import vla as V
     from repro.models.sharding import shape_tree
@@ -262,6 +265,18 @@ def test_kimi_moe_loop_runs_one_routed_kernel(one_chip):
            for name, result, op, _, attrs in body if op not in plumbing]
     experts = [(name, op, m.group(1)) for name, op, _, m in ops
                if m and f"/{M.EXPERTS}/" in m.group(1)]
+    comps = computations(text)
+
+    def fused(attrs):
+        for c in re.findall(r"calls=%([\w.\-]+)", attrs):
+            for _, _, _, _, inner in comps.get(c, []):
+                yield inner
+                yield from fused(inner)
+
+    named = {e[0] for e in experts}
+    leaks = [name for name, _, _, _, attrs in body if name not in named
+             and any(f"/{M.EXPERTS}/" in a for a in fused(attrs))]
+    assert not leaks, leaks
     kernels = [name for name, op, where in experts if op == "custom-call"]
     assert len(kernels) == 1, kernels
     assert [where for name, op, where in experts if op == "custom-call"
